@@ -16,8 +16,8 @@ occupy before — zero for both grow and shrink), and the shrink is drained
 online to the new capacity in a bounded number of batched eviction rounds.
 
 The failover rows (DESIGN.md §14) kill a shard mid-trace on a REAL
-4-shard mesh (subprocess with a forced host device count — the same
-pattern as the multi-shard tests) and measure the hit-rate dip depth and
+4-shard mesh (in-process on a four-device host; otherwise a child on
+four forced CPU host devices) and measure the hit-rate dip depth and
 time-to-recover with hot-bucket replication on vs off.  The replicated
 arm must dip shallower and drop fewer requests than the control — the
 read fan-out keeps serving a replicated bucket from its live secondary
@@ -41,73 +41,87 @@ from benchmarks.common import REPO_ROOT, emit
 from repro.workloads import ycsb
 
 
-# Runs under a forced 4-device host platform, so it must set XLA_FLAGS
-# before the first jax import — hence a child process, not a function.
+# The child for a process with fewer than four devices: four CPU host
+# devices, set before its first jax import.
 _FAILOVER_CHILD = r'''
 import json, os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import numpy as np
-from repro.core import CacheConfig
-from repro.elastic import HealthMonitor, run_scenario
-from repro.workloads.gen import failover_trace
-
-quick = sys.argv[1] == "quick"
-S, lanes, window = 4, 8, 16
-T = 192 if quick else 384
-t_fail = (T // 3 // window) * window
-t_rec = (2 * T // 3 // window) * window
-cfg = CacheConfig(n_buckets=1024, assoc=8, capacity=4096,
-                  experts=("lru", "lfu"))
-# 70% of requests on a 48-key zipf core homed entirely on the shard we
-# kill — the worst case for an unreplicated cluster, the case hot-key
-# replication exists for.
-trace = failover_trace(T, lanes, S, cfg.n_buckets, hot_shard=1,
-                       hot_fraction=0.7, n_hot=48, n_keys=3000, seed=7)
-timeline = [(t_fail, ("fail_shard", 1)), (t_rec, ("recover_shard", 1))]
-
-rows = []
-for name, rep_hot in (("failover_replicated", 96), ("failover_control", 0)):
-    res = run_scenario(cfg, trace.ravel(), timeline, n_shards=S,
-                       lanes_per_shard=lanes, horizon=T, window=window,
-                       health=HealthMonitor(S), replicate_hot=rep_hot,
-                       seed=7)
-    ws = res.windows
-    pre = float(np.mean([w["hit_rate"] for w in ws
-                         if w["t1"] <= t_fail and w["t0"] >= window]))
-    outage = [w for w in ws if w["t0"] >= t_fail and w["t1"] <= t_rec]
-    dip = pre - min(w["hit_rate"] for w in outage)
-    detect = next((w["t1"] for w in ws if not w["routed"][1]), t_rec)
-    rerouted = [w for w in outage if w["t0"] >= detect]
-    rec_hr = (float(np.mean([w["hit_rate"] for w in rerouted]))
-              if rerouted else 0.0)
-    after = [w for w in ws if w["t0"] >= t_rec]
-    recov = next((i for i, w in enumerate(after)
-                  if w["hit_rate"] >= 0.9 * pre), len(after))
-    rows.append(dict(name=name, us_per_call=0.0, hit_rate=rec_hr,
-                     pre_fail_hit_rate=round(pre, 4),
-                     dip_depth_pp=round(100 * dip, 2),
-                     detect_windows=(detect - t_fail) // window,
-                     recover_windows=recov,
-                     route_drops=sum(w["route_drops"] for w in ws),
-                     replica_writes=sum(w["replica_writes"] for w in ws),
-                     n_replicated=max(w["n_replicated"] for w in ws)))
-print("ROWS " + json.dumps(rows))
+from benchmarks.elasticity import _failover_scenarios
+print("ROWS " + json.dumps(_failover_scenarios(sys.argv[1] == "quick")))
 '''
+
+
+def _failover_scenarios(quick):
+    """Both kill-a-shard arms on a 4-shard mesh of this process's devices
+    (replication on, then off).  Returns the two rows."""
+    from repro.elastic import HealthMonitor
+    from repro.workloads.gen import failover_trace
+    S, lanes, window = 4, 8, 16
+    T = 192 if quick else 384
+    t_fail = (T // 3 // window) * window
+    t_rec = (2 * T // 3 // window) * window
+    cfg = CacheConfig(n_buckets=1024, assoc=8, capacity=4096,
+                      experts=("lru", "lfu"))
+    # 70% of requests on a 48-key zipf core homed entirely on the shard
+    # we kill — the worst case for an unreplicated cluster, the case
+    # hot-key replication exists for.
+    trace = failover_trace(T, lanes, S, cfg.n_buckets, hot_shard=1,
+                           hot_fraction=0.7, n_hot=48, n_keys=3000, seed=7)
+    timeline = [(t_fail, ("fail_shard", 1)), (t_rec, ("recover_shard", 1))]
+
+    rows = []
+    for name, rep_hot in (("failover_replicated", 96),
+                          ("failover_control", 0)):
+        res = run_scenario(cfg, trace.ravel(), timeline, n_shards=S,
+                           lanes_per_shard=lanes, horizon=T, window=window,
+                           health=HealthMonitor(S), replicate_hot=rep_hot,
+                           seed=7)
+        ws = res.windows
+        pre = float(np.mean([w["hit_rate"] for w in ws
+                             if w["t1"] <= t_fail and w["t0"] >= window]))
+        outage = [w for w in ws if w["t0"] >= t_fail and w["t1"] <= t_rec]
+        dip = pre - min(w["hit_rate"] for w in outage)
+        detect = next((w["t1"] for w in ws if not w["routed"][1]), t_rec)
+        rerouted = [w for w in outage if w["t0"] >= detect]
+        rec_hr = (float(np.mean([w["hit_rate"] for w in rerouted]))
+                  if rerouted else 0.0)
+        after = [w for w in ws if w["t0"] >= t_rec]
+        recov = next((i for i, w in enumerate(after)
+                      if w["hit_rate"] >= 0.9 * pre), len(after))
+        rows.append(dict(name=name, us_per_call=0.0, hit_rate=rec_hr,
+                         pre_fail_hit_rate=round(pre, 4),
+                         dip_depth_pp=round(100 * dip, 2),
+                         detect_windows=(detect - t_fail) // window,
+                         recover_windows=recov,
+                         route_drops=sum(w["route_drops"] for w in ws),
+                         replica_writes=sum(w["replica_writes"] for w in ws),
+                         n_replicated=max(w["n_replicated"] for w in ws)))
+    return rows
 
 
 def failover_rows(quick=False):
     """Kill-a-shard timeline on a real 4-shard mesh, replication vs
-    control, via a forced-device-count subprocess.  Returns the two
-    benchmark rows; asserts the replication win."""
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(REPO_ROOT, "src"))
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run(
-        [sys.executable, "-c", _FAILOVER_CHILD, "quick" if quick else "full"],
-        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=900)
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    payload = [ln for ln in out.stdout.splitlines() if ln.startswith("ROWS ")]
-    rows = json.loads(payload[-1][len("ROWS "):])
+    control.  A process that holds four devices runs it in-process; any
+    other starts a child on four CPU host devices (a child cannot share
+    this process's accelerator).  Returns the two benchmark rows;
+    asserts the replication win."""
+    import jax
+    if len(jax.devices()) >= 4:
+        rows = _failover_scenarios(quick)
+    else:
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       [os.path.join(REPO_ROOT, "src"), REPO_ROOT]))
+        env.pop("XLA_FLAGS", None)
+        out = subprocess.run(
+            [sys.executable, "-c", _FAILOVER_CHILD,
+             "quick" if quick else "full"],
+            capture_output=True, text=True, env=env, cwd=REPO_ROOT,
+            timeout=900)
+        assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+        payload = [ln for ln in out.stdout.splitlines()
+                   if ln.startswith("ROWS ")]
+        rows = json.loads(payload[-1][len("ROWS "):])
     rep, ctrl = rows
     assert rep["name"] == "failover_replicated"
     # The replication win, measured: the read fan-out serves replicated

@@ -16,6 +16,8 @@ def main() -> None:
                     help="comma-separated module subset")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (ablation, adaptivity, algorithms, efficiency,
                             elasticity, fc_sweep, resources, roofline_table,
                             sizes, tenants, throughput)

@@ -1,50 +1,57 @@
-"""Disaggregated-memory demo: the cache sharded over 8 (placeholder)
-devices with all_to_all request routing, then driven through a full
-elasticity timeline — memory grow (zero migration), compute grow/shrink
-(lane width with client-state carry-over), memory shrink (online drain),
-a workload shift, and a kill-a-shard failover leg (hot-bucket
-replication + heartbeat detection + rewarming recovery, DESIGN.md §14)
-— via the elastic runtime's scenario driver and the `dm.Cluster`
-membership handle.  Client lanes run a small L0 near-cache
-(`l0_entries=8`, DESIGN.md §15): the `l0hit` column counts requests
-served entirely lane-locally — watch it dip in the failover window
-(the epoch flush drops every lane's L0 wholesale) and climb back as
-the lanes refill.
+"""Disaggregated-memory demo: the cache sharded over every device this
+process finds, with all_to_all request routing, then driven through a
+full elasticity timeline — memory grow (zero migration), compute
+grow/shrink (lane width with client-state carry-over), memory shrink
+(online drain), a workload shift, and (with two or more shards) a
+kill-a-shard failover leg (hot-bucket replication + heartbeat detection
++ rewarming recovery, DESIGN.md §14) — via the elastic runtime's
+scenario driver and the `dm.Cluster` membership handle.  Client lanes
+run a small L0 near-cache (`l0_entries=8`, DESIGN.md §15): the `l0hit`
+column counts requests served entirely lane-locally — watch it dip in
+the failover window (the epoch flush drops every lane's L0 wholesale)
+and climb back as the lanes refill.
 
   PYTHONPATH=src python examples/dm_elastic_cache.py
-(must be its own process: it forces an 8-device host platform)
+
+One shard per device: on a four-chip host that is four shards.  On a
+CPU-only host, give the process several host devices to see the
+sharded path:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python examples/dm_elastic_cache.py
 """
-import os
-
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
+import jax
 import numpy as np
 
 from repro.core import CacheConfig
 from repro.elastic import HealthMonitor, run_scenario
 from repro.workloads import lru_friendly, zipfian
 
+S = len(jax.devices())
+lanes = 64 // S                          # 64 client lanes in all
 cfg = CacheConfig(n_buckets=1024, assoc=8, capacity=2048,
                   experts=("lru", "lfu"), l0_entries=8)
 
 timeline = [
     (100, ("set_capacity", 4096)),       # memory grow: one scalar/shard
-    (150, ("set_lanes", 16)),            # compute grow: 64 -> 128 lanes
-    (250, ("set_lanes", 8)),             # compute shrink: decommission flush
+    (150, ("set_lanes", 2 * lanes)),     # compute grow: 64 -> 128 lanes
+    (250, ("set_lanes", lanes)),         # compute shrink: decommission flush
     (300, ("set_capacity", 1024)),       # memory shrink: online drain
     (350, ("switch_workload", "shift")),  # recency-heavy phase
-    (400, ("fail_shard", 3)),            # shard 3's DRAM is gone; routing
-    #                                    # doesn't know yet — bounces until
-    #                                    # the heartbeat monitor re-routes
-    (475, ("recover_shard", 3)),         # replacement up: rewarm from the
-    #                                    # survivors, route home again
 ]
+if S >= 2:
+    timeline += [
+        (400, ("fail_shard", S - 1)),    # the shard's DRAM is gone; routing
+        #                                # doesn't know yet — bounces until
+        #                                # the heartbeat monitor re-routes
+        (475, ("recover_shard", S - 1)),  # replacement up: rewarm from the
+        #                                # survivors, route home again
+    ]
 res = run_scenario(
     cfg, zipfian(64 * 500, 20_000, seed=0), timeline,
-    n_shards=8, lanes_per_shard=8, horizon=500, window=25,
+    n_shards=S, lanes_per_shard=lanes, horizon=500, window=25,
     workloads={"shift": lru_friendly(20_000, seed=3)},
-    health=HealthMonitor(8),             # missed-beat failover detection
+    health=HealthMonitor(S),             # missed-beat failover detection
     replicate_hot=64)                    # hot-bucket replica election
 
 print(f"{'window':>10} {'cap':>5} {'lanes':>5} {'hit%':>6} "
@@ -61,11 +68,13 @@ for w in res.windows:
 resize_ev = [e for e in res.events
              if e["event"] in ("set_capacity", "set_lanes")]
 mig = sum(e["report"]["migration_bytes"] for e in resize_ev)
-rewarm = [e for e in res.events if e["event"] == "recover_shard"][0]
 print(f"\nresize events: {len(resize_ev)}, migrated bytes (measured): {mig}")
-print(f"failover: detected {[e['t'] for e in res.events if e['event'] == 'mark_failed']},"
-      f" rewarmed {rewarm['report']['drained_objects']} objects "
-      f"({rewarm['report']['migration_bytes']} bytes) on recovery")
+if S >= 2:
+    rewarm = [e for e in res.events if e["event"] == "recover_shard"][0]
+    print(f"failover: detected "
+          f"{[e['t'] for e in res.events if e['event'] == 'mark_failed']},"
+          f" rewarmed {rewarm['report']['drained_objects']} objects "
+          f"({rewarm['report']['migration_bytes']} bytes) on recovery")
 per_shard = np.asarray(res.cluster.dm.state.bytes_cached)
 print(f"final byte occupancy {per_shard.sum()} blocks <= budget "
       f"{res.windows[-1]['capacity']} blocks, per-shard: {per_shard}")

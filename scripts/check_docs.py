@@ -41,8 +41,7 @@ DOC_FILES = ("README.md", "DESIGN.md", "ROADMAP.md", "CHANGES.md")
 DOC_GLOBS = ("docs/*.md", "docs/**/*.md")
 
 # Examples run by the gate: each must complete with DeprecationWarning
-# promoted to an error.  dm_elastic_cache forces its own 8-device host
-# platform, so every example runs as a fresh subprocess.
+# promoted to an error, each as a fresh subprocess.
 EXAMPLES = ("examples/quickstart.py", "examples/dm_elastic_cache.py")
 
 _LINK_RE = re.compile(r"!?\[(?:[^\]]*)\]\(([^)\s]+)(?:\s+\"[^\"]*\")?\)")
@@ -129,8 +128,8 @@ def check_examples() -> list:
     findings = []
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    # The examples manage their own device counts; a stale XLA_FLAGS
-    # from the caller would fight dm_elastic_cache's own forcing.
+    # dm_elastic_cache shards over the devices it finds; a caller's
+    # XLA_FLAGS would change that count, so the gate runs without it.
     env.pop("XLA_FLAGS", None)
     for ex in EXAMPLES:
         path = os.path.join(REPO_ROOT, ex)

@@ -223,14 +223,15 @@ def audit_entry_points(widths=(1, 8), backends=("reference", "fused"),
                 findings += audit_closed(closed, "access_group",
                                          CONVERT_BUDGETS["access_group"])
             closed = jax.make_jaxpr(
-                lambda s, c, k: run_trace_grouped(cfg, s, c, k))(
-                    st, cl, jnp.ones((3, 2, n_clients), jnp.uint32))
+                lambda s, c, a, k: run_trace_grouped(cfg, s, c, k,
+                                                     stats=a))(
+                    st, cl, sa, jnp.ones((3, 2, n_clients), jnp.uint32))
             findings += audit_closed(closed, "run_trace_grouped",
                                      CONVERT_BUDGETS["run_trace_grouped"])
 
     # ranked_eviction: the fused kernel's public op wrapper.
     w, k, b, c = 20, 5, 8, 256
-    col = jnp.zeros((c + w,), jnp.uint32)
+    col = jnp.zeros((c,), jnp.uint32)
     closed = jax.make_jaxpr(
         lambda s, i, l, f, o, e, m, q, t: kops.ranked_eviction_op(
             s, i, l, f, o, e, m, q, t, window=w, k=k))(

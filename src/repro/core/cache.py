@@ -172,6 +172,12 @@ def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
             raise ValueError(
                 f"backend='fused' supports experts {kops.KERNEL_EXPERTS}; "
                 f"got {unsupported} (use backend='reference')")
+        if cfg.n_slots > kops.FUSED_MAX_SLOTS or cfg.n_slots % 128:
+            raise ValueError(
+                f"backend='fused' keeps the slot tables in VMEM: it needs "
+                f"n_slots a multiple of 128 and at most FUSED_MAX_SLOTS="
+                f"{kops.FUSED_MAX_SLOTS} (got n_slots={cfg.n_slots}; use "
+                "backend='reference')")
 
     if is_write is None:
         is_write = jnp.zeros((G, C), bool)
@@ -497,11 +503,10 @@ def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
     offs = jnp.minimum((u2[:, 1] * cfg.n_slots).astype(I32),
                        cfg.n_slots - 1)
     if fused:
-        wrap = lambda x: jnp.concatenate([x, x[:W]])
         victims_2d, cand_slot = kops.ranked_eviction_op(
-            wrap(state.size), wrap(state.insert_ts), wrap(state.last_ts),
-            wrap(state.freq), offs, e_choice, must_evict, quota_b, ts_req,
-            tenant=wrap(state.tenant) if multi else None, tfilt=tfilt,
+            state.size, state.insert_ts, state.last_ts, state.freq, offs,
+            e_choice, must_evict, quota_b, ts_req,
+            tenant=state.tenant if multi else None, tfilt=tfilt,
             window=W, k=K, experts=names)                     # [B, K], [B, E]
         take = victims_2d >= 0
     else:
@@ -901,8 +906,10 @@ def _run_trace_impl(cfg: CacheConfig, state: CacheState,
                     clients: ClientState, keys: jnp.ndarray,
                     is_write: jnp.ndarray | None = None,
                     obj_size: jnp.ndarray | None = None,
-                    tenant: jnp.ndarray | None = None) -> TraceResult:
-    """Run a [T, C] trace (T steps of C concurrent client ops)."""
+                    tenant: jnp.ndarray | None = None,
+                    stats: OpStats | None = None) -> TraceResult:
+    """Run a [T, C] trace (T steps of C concurrent client ops).  Counters
+    accumulate onto ``stats`` (fresh zeros when None)."""
     T, C = keys.shape
     if is_write is None:
         is_write = jnp.zeros((T, C), bool)
@@ -910,7 +917,8 @@ def _run_trace_impl(cfg: CacheConfig, state: CacheState,
         obj_size = jnp.ones((T, C), U32)
     if tenant is None:
         tenant = jnp.zeros((T, C), U32)
-    stats = init_stats()
+    if stats is None:
+        stats = init_stats()
 
     def step(carry, xs):
         st, cl, sa = carry
@@ -930,13 +938,15 @@ def _run_trace_grouped_impl(cfg: CacheConfig, state: CacheState,
                             clients: ClientState, keys: jnp.ndarray,
                             is_write: jnp.ndarray | None = None,
                             obj_size: jnp.ndarray | None = None,
-                            tenant: jnp.ndarray | None = None) -> TraceResult:
+                            tenant: jnp.ndarray | None = None,
+                            stats: OpStats | None = None) -> TraceResult:
     """Run a planned [NG, G, C] grouped trace: one scan step retires a
     whole G-round request group (see ``workloads.plan.plan_groups``).
 
     Returns per-round hit/op counts ([NG*G]) so grouped and sequential
     runs compare round-for-round; the weight trajectory is step-granular
-    (each group's end weights repeated for its G rounds)."""
+    (each group's end weights repeated for its G rounds).  Counters
+    accumulate onto ``stats`` (fresh zeros when None)."""
     NG, G, C = keys.shape
     if is_write is None:
         is_write = jnp.zeros((NG, G, C), bool)
@@ -944,7 +954,8 @@ def _run_trace_grouped_impl(cfg: CacheConfig, state: CacheState,
         obj_size = jnp.ones((NG, G, C), U32)
     if tenant is None:
         tenant = jnp.zeros((NG, G, C), U32)
-    stats = init_stats()
+    if stats is None:
+        stats = init_stats()
 
     def step(carry, xs):
         st, cl, sa = carry
@@ -984,13 +995,14 @@ def run_trace_grouped(cfg: CacheConfig, state: CacheState,
                       clients: ClientState, keys: jnp.ndarray,
                       is_write: jnp.ndarray | None = None,
                       obj_size: jnp.ndarray | None = None,
-                      tenant: jnp.ndarray | None = None) -> TraceResult:
+                      tenant: jnp.ndarray | None = None,
+                      stats: OpStats | None = None) -> TraceResult:
     """Deprecated grouped trace driver: use ``repro.core.execute`` with
     a precomputed plan or ``plan="adaptive"`` (bit-identical results for
     the same plan)."""
     _deprecated_entrypoint("run_trace_grouped")
     return _run_trace_grouped_impl(cfg, state, clients, keys, is_write,
-                                   obj_size, tenant)
+                                   obj_size, tenant, stats)
 
 
 def make_cache(cfg: CacheConfig, n_clients: int, seed: int = 0):

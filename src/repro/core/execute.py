@@ -132,12 +132,12 @@ def _runner(cfg: CacheConfig, grouped: bool, donate: bool,
         return hit
     impl = _run_trace_grouped_impl if grouped else _run_trace_impl
 
-    def run(state, clients, keys, is_write, obj_size, tenant):
+    def run(state, clients, stats, keys, is_write, obj_size, tenant):
         # force_interpret binds at trace time; the cache key carries the
         # flag so compiled executables never alias across overrides.
         with force_interpret(interpret):
             return impl(cfg, state, clients, keys, is_write, obj_size,
-                        tenant)
+                        tenant, stats)
 
     fn = jax.jit(run, donate_argnums=(0, 1) if donate else ())
     entry = (fn, set())
@@ -348,13 +348,12 @@ def execute(cache, trace, *, plan=_UNSET, exec_cfg: ExecConfig | None = None,
         shape_key = tuple(a.shape for a in args)
         was_warm = shape_key in warm
         t0 = time.perf_counter()
-        res: TraceResult = fn(state, clients, *args)
+        res: TraceResult = fn(state, clients, stats, *args)
         res = jax.block_until_ready(res)
         wall = time.perf_counter() - t0
         warm.add(shape_key)
         wall_total += wall
-        state, clients = res.state, res.clients
-        stats = jax.tree.map(lambda a, b: a + b, stats, res.stats)
+        state, clients, stats = res.state, res.clients, res.stats
         hits_parts.append(np.asarray(res.hits))
         ops_parts.append(np.asarray(res.ops))
         w_parts.append(np.asarray(res.weights))

@@ -23,7 +23,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.cache import access_group, apply_penalties
 from repro.core.hashing import bucket_of, hash_key, splitmix32
@@ -101,12 +100,11 @@ def _unpad_clients(orig: ClientState, padded: ClientState,
 
 
 def _mesh(n: int) -> Mesh:
+    """A 1-D pool mesh over the first ``n`` devices of this process (all
+    of them when it has fewer)."""
     devs = jax.devices()[:n]
-    try:  # axis_types / AxisType only exist on newer jax releases
-        return jax.make_mesh((len(devs),), (AXIS,),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        return jax.make_mesh((len(devs),), (AXIS,))
+    return jax.make_mesh((len(devs),), (AXIS,), devices=devs,
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def dm_make(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
@@ -461,13 +459,15 @@ def _dm_access_impl(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
 
     spec_member = jax.tree.map(lambda _: P(), member)
 
-    fn = shard_map(
+    # Jitted so an eager caller runs the same program a traced one does
+    # (an eager shard_map call fails JAX's output-sharding check here).
+    fn = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(spec_state, spec_clients, spec_stats,
                   P(None, AXIS), P(None, AXIS), P(None, AXIS),
                   P(None, AXIS), spec_member),
         out_specs=(spec_state, spec_clients, spec_stats, P(None, AXIS)),
-        check_rep=False)
+        check_vma=False))
     state, clients, stats, hits = fn(dm.state, dm.clients, dm.stats,
                                      keys, is_write, obj_size,
                                      tenant.astype(jnp.uint32), member)
@@ -587,14 +587,16 @@ def dm_execute(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
     spec_stats = jax.tree.map(lambda _: P(AXIS), dm.stats)
     spec_member = jax.tree.map(lambda _: P(), member)
 
-    fn = shard_map(
+    # Jitted so an eager caller runs the same program a traced one does
+    # (an eager shard_map call fails JAX's output-sharding check here).
+    fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(spec_state, spec_clients, spec_stats,
                   P(None, None, AXIS), P(None, None, AXIS),
                   P(None, None, AXIS), P(None, None, AXIS), spec_member),
         out_specs=(spec_state, spec_clients, spec_stats,
                    P(None, None, AXIS)),
-        check_rep=False)
+        check_vma=False))
     state, clients, stats, hits = fn(dm.state, dm.clients, dm.stats,
                                      keys, is_write, obj_size, tenant,
                                      member)

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import priority as prio
 from repro.core.cache import _is_live, _md_view
@@ -199,11 +198,11 @@ def _drain_fn(mesh: Mesh, local_cfg: CacheConfig, batch: int):
     def run(state, stats):
         spec_state = jax.tree.map(lambda _: P(AXIS), state)
         spec_stats = jax.tree.map(lambda _: P(AXIS), stats)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_drain_shard, local_cfg, batch), mesh=mesh,
             in_specs=(spec_state, spec_stats),
             out_specs=(spec_state, spec_stats, P(AXIS), P(AXIS)),
-            check_rep=False)
+            check_vma=False)
         return fn(state, stats)
     return jax.jit(run)
 

@@ -1,21 +1,17 @@
-"""Hash-table bucket-probe Pallas kernels.
+"""Fused Get-path probe Pallas kernel (``access_probe``).
 
-The client-side Get path: hash the key (splitmix32 on the VPU, pure u32
-ALU), locate the bucket, compare the ``assoc`` slots, return (found, slot).
-On DM this is the 1-RDMA_READ bucket fetch; here the bucket rows stream
-from the VMEM-resident atomic fields.
+The client-side Get path of the ``fused`` backend of ``core/cache.py``:
+one pass that performs the bucket probe *and* the embedded-history match
+(paper §4.3.1) against the sample-friendly table, returning
+(found, slot, hist_found, hist_slot).  On DM this is the 1-RDMA_READ
+bucket fetch; here the bucket rows stream from the VMEM-resident slot
+columns.
 
-Two kernels live here:
-
-* ``bucket_lookup`` — the standalone probe (found, slot) kept as the
-  minimal demo/benchmark kernel.
-* ``access_probe`` — the production Get path used by the ``fused``
-  backend of ``core/cache.py``: one fused pass that performs the bucket
-  probe *and* the embedded-history match (paper §4.3.1) against the
-  sample-friendly table, returning (found, slot, hist_found, hist_slot).
-
-Both pad the request batch internally to a multiple of ``block_b`` and
-mask, so callers with odd batch widths never crash.
+Layout (``runtime.as_rows``): each column is ``i32[n / 128, 128]``, so a
+bucket of ``assoc`` slots is the lane range ``[b*assoc % 128, +assoc)``
+of row ``b*assoc // 128``.  Per request block, a scalar loop copies each
+request's bucket row (row index from SMEM) into a VMEM scratch; the
+match then runs vectorized over ``[block_b, 128]`` with a lane mask.
 """
 
 from __future__ import annotations
@@ -25,161 +21,108 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.runtime import resolve_interpret
-
-
-def _hash_u32(x):
-    # Mirror of repro.core.hashing.splitmix32 — the semantics contract is
-    # enforced by the kernel-vs-reference tests.
-    x = (x + jnp.uint32(0x9E3779B9)).astype(jnp.uint32)
-    x = (x ^ (x >> 16)) * jnp.uint32(0x85EBCA6B)
-    x = (x ^ (x >> 13)) * jnp.uint32(0xC2B2AE35)
-    return (x ^ (x >> 16)).astype(jnp.uint32)
+from repro.core.hashing import bucket_of, hash_key
+from repro.kernels.runtime import (LANES, VMEM_LIMIT_BYTES, as_column,
+                                   as_i32, as_rows, auto_block_b,
+                                   resolve_interpret)
 
 
-def _gather_rows(refs, base, width, block_b, vectorized):
-    """[block_b, width] bucket-row gather per table column: per-row
-    dynamic slices for compiled Mosaic, one vectorized gather for the
-    interpreter (a python slice loop costs O(block_b) interpreted ops)."""
-    if vectorized:
-        idx = base[:, None] + jax.lax.broadcasted_iota(
-            jnp.int32, (base.shape[0], width), 1)
-        return [ref[...][idx] for ref in refs]
-    return [jnp.stack([
-        jax.lax.dynamic_slice(ref[...], (base[i],), (width,))
-        for i in range(block_b)]) for ref in refs]
+def _first_lane(mask, lane):
+    """[b, 1] index of the first True lane of each row (LANES if none)."""
+    return jnp.min(jnp.where(mask, lane, LANES), axis=1, keepdims=True)
 
 
-def _pad_batch(x, block_b, fill=0):
-    """Pad a [B, ...] batch to a multiple of block_b with ``fill``."""
-    B = x.shape[0]
-    rem = B % block_b
-    if rem == 0:
-        return x, B
-    pad = block_b - rem
-    padding = jnp.full((pad,) + x.shape[1:], fill, x.dtype)
-    return jnp.concatenate([x, padding], axis=0), B
+def _probe_kernel(rows_ref, hctr_ref, tk_ref, ts_ref, th_ref, tp_ref,
+                  keys_ref, kh_ref, base_ref, found_ref, slot_ref, hfound_ref,
+                  hslot_ref, gk, gs, gh, gp, *, assoc, history_len, block_b):
+    # Gather each request's bucket row: scalar row index, one row copy
+    # per column (the bucket read).
+    first_req = pl.program_id(0) * block_b
 
+    def gather(i, carry):
+        r = rows_ref[first_req + i]
+        for src, dst in ((tk_ref, gk), (ts_ref, gs), (th_ref, gh),
+                         (tp_ref, gp)):
+            dst[pl.ds(i, 1), :] = src[pl.ds(r, 1), :]
+        return carry
 
-def _kernel(tkey_ref, tsize_ref, keys_ref, found_ref, slot_ref, *,
-            assoc, n_buckets, block_b, vectorized=False):
-    keys = keys_ref[...]
-    kh = _hash_u32(keys)
-    bucket = (kh % jnp.uint32(n_buckets)).astype(jnp.int32)
-    base = bucket * assoc
-    tk, ts = _gather_rows((tkey_ref, tsize_ref), base, assoc, block_b,
-                          vectorized)
-    live = (ts > 0) & (ts < 255)
-    match = live & (tk == keys[:, None])
-    found = jnp.any(match, axis=1)
-    arg = jnp.argmax(match, axis=1)
-    slot = base + arg.astype(jnp.int32)
-    found_ref[...] = found
-    slot_ref[...] = jnp.where(found, slot, -1).astype(jnp.int32)
+    jax.lax.fori_loop(0, block_b, gather, 0)
 
+    base = base_ref[...]                                   # [b, 1] slot
+    lo = base & (LANES - 1)                                # lane offset
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_b, LANES), 1)
+    in_bucket = (lane >= lo) & (lane < lo + assoc)
+    size = gs[...]
 
-@functools.partial(jax.jit, static_argnames=("assoc", "block_b", "interpret"))
-def bucket_lookup(table_key, table_size, keys, *, assoc: int = 8,
-                  block_b: int = 8, interpret: bool | None = None):
-    """table_key: u32[n_slots]; table_size: u32[n_slots]; keys: u32[B].
-    Returns (found bool[B], slot i32[B]). B need not divide block_b —
-    the batch is padded internally (key 0 never matches a live slot).
-    ``interpret=None`` resolves to the backend default (compiled on
-    TPU, interpreter elsewhere)."""
-    interpret = resolve_interpret(interpret)
-    keys, B = _pad_batch(keys, block_b)
-    Bp = keys.shape[0]
-    n_buckets = table_key.shape[0] // assoc
-    grid = (Bp // block_b,)
-    table_spec = pl.BlockSpec(table_key.shape, lambda i: (0,))
-    fn = functools.partial(_kernel, assoc=assoc, n_buckets=n_buckets,
-                           block_b=block_b, vectorized=interpret)
-    found, slot = pl.pallas_call(
-        fn,
-        grid=grid,
-        in_specs=[table_spec, table_spec,
-                  pl.BlockSpec((block_b,), lambda i: (i,))],
-        out_specs=(pl.BlockSpec((block_b,), lambda i: (i,)),
-                   pl.BlockSpec((block_b,), lambda i: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-                   jax.ShapeDtypeStruct((Bp,), jnp.int32)),
-        interpret=interpret,
-    )(table_key, table_size.astype(jnp.uint32), keys)
-    return found[:B], slot[:B]
+    # Live-object match (first matching slot, as the reference argmax).
+    live = in_bucket & (size != 0) & (size != 255)
+    first = _first_lane(live & (gk[...] == keys_ref[...]), lane)
+    found = first < LANES
 
+    # Embedded history match on the same bucket read: size == 255 slots
+    # tagged with a logical-FIFO id in `ptr`.  The unsigned age
+    # (hist_ctr - ptr) < history_len is the signed age in [0, len).
+    age = hctr_ref[0] - gp[...]
+    h_valid = in_bucket & (size == 255) & (age >= 0) & (age < history_len)
+    hfirst = _first_lane(h_valid & (gh[...] == kh_ref[...]), lane)
+    hit_hist = hfirst < LANES
 
-def _probe_kernel(tkey_ref, tsize_ref, thash_ref, tptr_ref, keys_ref,
-                  hctr_ref, found_ref, slot_ref, hfound_ref, hslot_ref, *,
-                  assoc, n_buckets, history_len, block_b, vectorized=False):
-    keys = keys_ref[...]
-    kh = _hash_u32(keys)
-    bucket = (kh % jnp.uint32(n_buckets)).astype(jnp.int32)
-    base = bucket * assoc
-
-    tk, ts, th, tp = _gather_rows(
-        (tkey_ref, tsize_ref, thash_ref, tptr_ref), base, assoc, block_b,
-        vectorized)                                         # [block_b, A]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block_b, assoc), 1)
-    bslots = base[:, None] + cols
-
-    # Live-object match.
-    live = (ts > 0) & (ts < 255)
-    match = live & (tk == keys[:, None])
-    found = jnp.any(match, axis=1)
-    mslot = jnp.take_along_axis(
-        bslots, jnp.argmax(match, axis=1)[:, None], axis=1)[:, 0]
-
-    # Embedded history match: same bucket read carries the history entries
-    # (size == 255 slots tagged with a logical-FIFO id in `ptr`).
-    is_hist = ts == 255
-    age = (hctr_ref[0] - tp).astype(jnp.uint32)             # wrap-around age
-    h_valid = is_hist & (age < jnp.uint32(history_len))
-    h_match = h_valid & (th == kh[:, None])
-    hfound = jnp.any(h_match, axis=1) & ~found
-    hslot = jnp.take_along_axis(
-        bslots, jnp.argmax(h_match, axis=1)[:, None], axis=1)[:, 0]
-
-    found_ref[...] = found
-    slot_ref[...] = jnp.where(found, mslot, -1).astype(jnp.int32)
-    hfound_ref[...] = hfound
-    hslot_ref[...] = hslot.astype(jnp.int32)
+    found_ref[...] = found.astype(jnp.int32)
+    slot_ref[...] = jnp.where(found, base + first - lo, -1)
+    hfound_ref[...] = (hit_hist & ~found).astype(jnp.int32)
+    hslot_ref[...] = base + jnp.where(hit_hist, hfirst - lo, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("assoc", "history_len",
                                              "block_b", "interpret"))
 def access_probe(table_key, table_size, table_hash, table_ptr, keys,
                  hist_ctr, *, assoc: int = 8, history_len: int = 1024,
-                 block_b: int = 8, interpret: bool | None = None):
+                 block_b: int | None = None, interpret: bool | None = None):
     """Fused Get-path probe: bucket match + embedded-history match.
 
-    table_*: u32[n_slots]; keys: u32[B]; hist_ctr: u32[] global history
-    counter. Returns (found bool[B], slot i32[B] (-1 miss),
-    hist_found bool[B], hist_slot i32[B] — the matching history slot,
-    bucket base where there is no match, mirroring the reference path).
+    table_*: u32[n_slots] (n_slots a multiple of 128); keys: u32[B];
+    hist_ctr: u32[] global history counter.  Returns (found bool[B],
+    slot i32[B] (-1 miss), hist_found bool[B], hist_slot i32[B] — the
+    matching history slot, bucket base where there is no match,
+    mirroring the reference path).  The batch is padded internally to a
+    multiple of ``block_b``.
     """
     interpret = resolve_interpret(interpret)
-    keys, B = _pad_batch(keys, block_b)
-    Bp = keys.shape[0]
+    if LANES % assoc:
+        raise ValueError(f"assoc={assoc} must divide {LANES}")
+    if not 0 < history_len < 2**31:
+        raise ValueError(f"history_len={history_len} out of range")
+    B = keys.shape[0]
+    block_b = block_b or auto_block_b(B, interpret)
+    Bp = -(-B // block_b) * block_b
     n_buckets = table_key.shape[0] // assoc
-    grid = (Bp // block_b,)
-    table_spec = pl.BlockSpec(table_key.shape, lambda i: (0,))
-    lane_spec = pl.BlockSpec((block_b,), lambda i: (i,))
-    fn = functools.partial(_probe_kernel, assoc=assoc, n_buckets=n_buckets,
-                           history_len=history_len, block_b=block_b,
-                           vectorized=interpret)
-    found, slot, hfound, hslot = pl.pallas_call(
+    kh = hash_key(keys)
+    base = bucket_of(kh, n_buckets).astype(jnp.int32) * assoc
+    rows = jnp.concatenate([base // LANES,
+                            jnp.zeros((Bp - B,), jnp.int32)])
+    col = pl.BlockSpec((block_b, 1), lambda i: (i, 0))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    fn = functools.partial(_probe_kernel, assoc=assoc,
+                           history_len=history_len, block_b=block_b)
+    out = pl.pallas_call(
         fn,
-        grid=grid,
-        in_specs=[table_spec, table_spec, table_spec, table_spec, lane_spec,
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=(lane_spec, lane_spec, lane_spec, lane_spec),
-        out_shape=(jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-                   jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                   jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-                   jax.ShapeDtypeStruct((Bp,), jnp.int32)),
+        grid=(Bp // block_b,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  vmem, vmem, vmem, vmem, col, col, col],
+        out_specs=(col, col, col, col),
+        out_shape=tuple(jax.ShapeDtypeStruct((Bp, 1), jnp.int32)
+                        for _ in range(4)),
+        scratch_shapes=[pltpu.VMEM((block_b, LANES), jnp.int32)
+                        for _ in range(4)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(table_key.astype(jnp.uint32), table_size.astype(jnp.uint32),
-      table_hash.astype(jnp.uint32), table_ptr.astype(jnp.uint32), keys,
-      jnp.asarray(hist_ctr, jnp.uint32).reshape(1))
-    return found[:B], slot[:B], hfound[:B], hslot[:B]
+    )(rows, as_i32(hist_ctr).reshape(1),
+      as_rows(table_key), as_rows(table_size), as_rows(table_hash),
+      as_rows(table_ptr), as_column(as_i32(keys), Bp),
+      as_column(as_i32(kh), Bp), as_column(base, Bp))
+    found, slot, hfound, hslot = (o[:B, 0] for o in out)
+    return found.astype(bool), slot, hfound.astype(bool), hslot

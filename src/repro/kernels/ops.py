@@ -1,7 +1,7 @@
 """Public jitted wrappers for the Pallas kernels.
 
 ``interpret`` resolves inside each kernel via
-``repro.kernels.runtime.interpret_default`` — interpreter on CPU (the
+``repro.kernels.runtime.resolve_interpret`` — interpreter on CPU (the
 kernel body executes in Python per the brief), compiled Mosaic on TPU.
 """
 
@@ -9,40 +9,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.bucket_lookup import access_probe, bucket_lookup
+from repro.kernels.bucket_lookup import access_probe
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.metadata_update import hit_metadata_update, metadata_update
-from repro.kernels.runtime import interpret_default
-from repro.kernels.sampled_eviction import (KERNEL_EXPERTS, ranked_eviction,
-                                            sampled_eviction)
+from repro.kernels.metadata_update import hit_metadata_update
+from repro.kernels.runtime import FUSED_MAX_SLOTS
+from repro.kernels.sampled_eviction import KERNEL_EXPERTS, ranked_eviction
 
-__all__ = ["sampled_eviction_op", "ranked_eviction_op", "bucket_lookup_op",
-           "access_probe_op", "metadata_update_op", "hit_metadata_update_op",
-           "flash_attention_op", "KERNEL_EXPERTS"]
-
-
-def _auto_block_b(n: int, cap: int = 256) -> int:
-    """Scale the request-tile width with the batch — but only for the
-    interpreter, whose vectorized-gather branch makes per-cell overhead
-    the dominant cost. Compiled Mosaic kernels unroll ``block_b``
-    dynamic slices per grid cell, so widening the tile there balloons
-    compile time instead; they keep the tuned default."""
-    if not interpret_default():
-        return 8
-    return max(8, min(cap, n))
-
-
-def sampled_eviction_op(size, insert_ts, last_ts, freq, offsets, e_choice,
-                        clock, *, window=20, k=5, experts=("lru", "lfu"),
-                        block_b=8):
-    """Fused window-gather -> priorities -> candidates -> victim.
-
-    Table arrays must be padded by `window` at the tail (empty slots)."""
-    return sampled_eviction(
-        size.astype(jnp.float32), insert_ts.astype(jnp.float32),
-        last_ts.astype(jnp.float32), freq.astype(jnp.float32),
-        offsets.astype(jnp.int32), e_choice.astype(jnp.int32), clock,
-        window=window, k=k, experts=tuple(experts), block_b=block_b)
+__all__ = ["ranked_eviction_op", "access_probe_op", "hit_metadata_update_op",
+           "flash_attention_op", "KERNEL_EXPERTS", "FUSED_MAX_SLOTS"]
 
 
 def ranked_eviction_op(size, insert_ts, last_ts, freq, offsets, e_choice,
@@ -52,55 +26,34 @@ def ranked_eviction_op(size, insert_ts, last_ts, freq, offsets, e_choice,
     peeled until their summed sizes cover the op's `quota` blocks (at
     most k victims; `quota` is i32[B] or a scalar broadcast), each op
     evaluating time-dependent priorities at its own per-request
-    timestamp ``ts`` [B]. Table arrays are f32[C + window] wrap-padded
-    (`concatenate([x, x[:window]])`); returned slots are mod C.
-    ``tenant`` (wrap-padded owner column) + ``tfilt`` (i32[B], -1 = no
-    filter) scope a budget-enforcing op's sample to its own tenant's
-    slots (DESIGN.md §11)."""
+    timestamp ``ts`` [B].  Table columns are the cache's u32[C] slot
+    columns; windows wrap mod C.  ``tenant`` (owner column) + ``tfilt``
+    (i32[B], -1 = no filter) scope a budget-enforcing op's sample to its
+    own tenant's slots (DESIGN.md §11)."""
     return ranked_eviction(
-        size.astype(jnp.float32), insert_ts.astype(jnp.float32),
-        last_ts.astype(jnp.float32), freq.astype(jnp.float32),
-        offsets.astype(jnp.int32), e_choice.astype(jnp.int32),
-        must_evict.astype(jnp.bool_), quota, ts.astype(jnp.float32),
-        None if tenant is None else tenant.astype(jnp.float32),
+        size, insert_ts, last_ts, freq, offsets.astype(jnp.int32),
+        e_choice.astype(jnp.int32), must_evict.astype(jnp.bool_), quota,
+        ts.astype(jnp.float32), tenant,
         None if tfilt is None else tfilt.astype(jnp.int32),
-        window=window, k=k, experts=tuple(experts),
-        block_b=block_b or _auto_block_b(offsets.shape[0]))
+        window=window, k=k, experts=tuple(experts), block_b=block_b)
 
 
 def access_probe_op(table_key, table_size, table_hash, table_ptr, keys,
                     hist_ctr, *, assoc=8, history_len=1024, block_b=None):
     """Fused Get-path probe: bucket match + embedded-history match."""
-    return access_probe(table_key, table_size, table_hash, table_ptr, keys,
-                        hist_ctr, assoc=assoc, history_len=history_len,
-                        block_b=block_b or _auto_block_b(keys.shape[0]))
-
-
-def bucket_lookup_op(table_key, table_size, keys, *, assoc=8, block_b=8):
-    return bucket_lookup(table_key.astype(jnp.uint32),
-                         table_size.astype(jnp.uint32),
-                         keys.astype(jnp.uint32), assoc=assoc,
-                         block_b=block_b)
-
-
-def metadata_update_op(freq, last_ts, slots, deltas, clock, *, block_c=512):
-    return metadata_update(freq.astype(jnp.float32),
-                           last_ts.astype(jnp.float32),
-                           slots.astype(jnp.int32),
-                           deltas.astype(jnp.float32), clock,
-                           block_c=block_c)
+    return access_probe(table_key, table_size, table_hash, table_ptr,
+                        jnp.asarray(keys, jnp.uint32), hist_ctr, assoc=assoc,
+                        history_len=history_len, block_b=block_b)
 
 
 def hit_metadata_update_op(freq, last_ts, ext, hit_slots, hit_ts, emit_slots,
                            emit_deltas, *, block_c=512):
     """Fused hit-side metadata update: last_ts max + ext columns at hit
     slots (at per-hit request timestamps ``hit_ts`` [Bh]), combining freq
-    FAA at FC-flush slots. freq/last_ts keep their caller dtype (u32 in
-    the cache) — no f32 round-trip of timestamps."""
-    return hit_metadata_update(
-        freq, last_ts, ext.astype(jnp.float32), hit_slots.astype(jnp.int32),
-        hit_ts, emit_slots.astype(jnp.int32), emit_deltas.astype(jnp.float32),
-        block_c=block_c)
+    FAA at FC-flush slots.  freq/last_ts are u32 end to end — no f32
+    round-trip of timestamps."""
+    return hit_metadata_update(freq, last_ts, ext, hit_slots, hit_ts,
+                               emit_slots, emit_deltas, block_c=block_c)
 
 
 def flash_attention_op(q, k, v, *, blk_q=128, blk_k=128):

@@ -11,9 +11,6 @@ import jax.numpy as jnp
 
 from repro.core.hashing import bucket_of, hash_key
 
-NEG_INF = -2.0e38
-
-
 def priorities_ref(size, insert_ts, last_ts, freq, clock, experts):
     """Stacked eviction priorities [..., E] for the kernel's expert set.
 
@@ -36,35 +33,6 @@ def priorities_ref(size, insert_ts, last_ts, freq, clock, experts):
     return jnp.stack(out, axis=-1)
 
 
-def sampled_eviction_ref(size, insert_ts, last_ts, freq, offsets, e_choice,
-                         clock, *, window: int, k: int, experts):
-    """Reference for the fused sampled-eviction kernel.
-
-    Args:
-      size/insert_ts/last_ts/freq: f32[C + window] (caller pads the tail
-        so windows never wrap).
-      offsets: i32[B] window starts in [0, C).
-      e_choice: i32[B] expert chosen per request (from local weights).
-    Returns:
-      victim: i32[B] slot index (-1 if no live object sampled)
-      cand:   i32[B, E] per-expert candidate slot (-1 if none live)
-    """
-    B = offsets.shape[0]
-    idx = offsets[:, None] + jnp.arange(window)[None, :]          # [B, W]
-    s = size[idx]
-    live = (s > 0) & (s < 255)
-    in_sample = live & (jnp.cumsum(live, axis=1) <= k)
-    pr = priorities_ref(s, insert_ts[idx], last_ts[idx], freq[idx],
-                        clock, experts)                           # [B, W, E]
-    pr = jnp.where(in_sample[..., None], pr, jnp.inf)
-    cand_w = jnp.argmin(pr, axis=1)                               # [B, E]
-    cand = jnp.take_along_axis(idx, cand_w, axis=1)
-    any_live = jnp.any(in_sample, axis=1)
-    cand = jnp.where(any_live[:, None], cand, -1)
-    victim = jnp.take_along_axis(cand, e_choice[:, None], axis=1)[:, 0]
-    return victim.astype(jnp.int32), cand.astype(jnp.int32)
-
-
 def ranked_eviction_ref(size, insert_ts, last_ts, freq, offsets, e_choice,
                         must_evict, quota, ts, *, window: int, k: int,
                         experts, tenant=None, tfilt=None):
@@ -76,11 +44,11 @@ def ranked_eviction_ref(size, insert_ts, last_ts, freq, offsets, e_choice,
     claims the shortest ranked prefix of sampled victims whose summed
     sizes (64B blocks) reach its ``quota`` (scalar or per-op i32[B]),
     at most ``k`` victims.  Uniform 1-block objects recover the old
-    take-`quota`-victims rule.  Table arrays are f32[C + window]
-    wrap-padded; returned slots mod C.
+    take-`quota`-victims rule.  Table arrays are the [C] slot columns;
+    windows wrap mod C.
 
-    Multi-tenant scoping (DESIGN.md §11): ``tenant`` is the wrap-padded
-    per-slot owner column and ``tfilt`` i32[B] restricts op b's sample
+    Multi-tenant scoping (DESIGN.md §11): ``tenant`` is the per-slot
+    owner column and ``tfilt`` i32[B] restricts op b's sample
     to slots of that tenant (-1 = unfiltered shared-pool sample); both
     default to the single-tenant behavior.
 
@@ -89,21 +57,22 @@ def ranked_eviction_ref(size, insert_ts, last_ts, freq, offsets, e_choice,
       cand:    i32[B, E] per-expert argmin candidate.
     """
     B = offsets.shape[0]
-    C = size.shape[0] - window
+    C = size.shape[0]
     quota = jnp.broadcast_to(jnp.asarray(quota, jnp.float32), (B,))
-    idx = offsets[:, None] + jnp.arange(window)[None, :]          # [B, W]
-    s = size[idx]
+    idx = (offsets[:, None] + jnp.arange(window)[None, :]) % C    # [B, W]
+    f32 = lambda x: x[idx].astype(jnp.float32)
+    s = f32(size)
     live = (s > 0) & (s < 255)
     if tenant is not None and tfilt is not None:
         tf = jnp.asarray(tfilt, jnp.int32)
         live = live & ((tf[:, None] < 0)
                        | (tenant[idx].astype(jnp.int32) == tf[:, None]))
     in_sample = live & (jnp.cumsum(live, axis=1) <= k)
-    pr = priorities_ref(s, insert_ts[idx], last_ts[idx], freq[idx],
+    pr = priorities_ref(s, f32(insert_ts), f32(last_ts), f32(freq),
                         ts[:, None], experts)                     # [B, W, E]
     pr = jnp.where(in_sample[..., None], pr, jnp.inf)
     cand_w = jnp.argmin(pr, axis=1)                               # [B, E]
-    cand = jnp.take_along_axis(idx, cand_w, axis=1) % C
+    cand = jnp.take_along_axis(idx, cand_w, axis=1)
 
     pr_sel = jnp.take_along_axis(
         pr, e_choice[:, None, None], axis=2)[:, :, 0]             # [B, W]
@@ -119,7 +88,7 @@ def ranked_eviction_ref(size, insert_ts, last_ts, freq, offsets, e_choice,
     freed_before = jnp.cumsum(ranked_blocks, axis=1) - ranked_blocks
     take = ((freed_before < quota[:, None]) & ranked_live
             & must_evict[:, None])
-    victims = jnp.where(take, ranked_idx % C, -1)[:, :k]
+    victims = jnp.where(take, ranked_idx, -1)[:, :k]
     return victims.astype(jnp.int32), cand.astype(jnp.int32)
 
 
@@ -184,30 +153,3 @@ def hit_metadata_update_ref(freq, last_ts, ext, hit_slots, hit_ts,
     new_ext = jnp.stack([ts0, ts1, crf, gap], axis=-1)
     ext2 = jnp.where(touched[:, None], new_ext, ext)
     return freq2, last2, ext2
-
-
-def bucket_lookup_ref(table_key, table_size, keys, *, assoc: int):
-    """Reference hash-table probe.
-
-    Returns (found bool[B], slot i32[B] (-1 if missing))."""
-    n_buckets = table_key.shape[0] // assoc
-    kh = hash_key(keys)
-    bucket = bucket_of(kh, n_buckets)
-    slots = bucket[:, None] * assoc + jnp.arange(assoc)[None, :]
-    live = (table_size[slots] > 0) & (table_size[slots] < 255)
-    match = live & (table_key[slots] == keys[:, None])
-    found = jnp.any(match, axis=1)
-    slot = jnp.take_along_axis(slots, jnp.argmax(match, axis=1)[:, None],
-                               axis=1)[:, 0]
-    return found, jnp.where(found, slot, -1).astype(jnp.int32)
-
-
-def metadata_update_ref(freq, last_ts, slots, deltas, clock):
-    """Reference combining metadata update (the remote FAA + stateless
-    write): freq[slot] += delta; last_ts[slot] = max(last_ts, clock).
-    slots: i32[B] with -1 = no-op."""
-    ok = slots >= 0
-    idx = jnp.where(ok, slots, freq.shape[0])
-    freq2 = freq.at[idx].add(jnp.where(ok, deltas, 0), mode="drop")
-    last2 = last_ts.at[idx].max(clock, mode="drop")
-    return freq2, last2

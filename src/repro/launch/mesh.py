@@ -15,11 +15,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    try:  # axis_types / AxisType only exist on newer jax releases
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -146,8 +146,7 @@ class TestAstLint:
 
 class TestJaxprAudit:
     def test_jx001_wide_dtype(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64) * 2)(
                     jnp.ones((4,), jnp.float32))

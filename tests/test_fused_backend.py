@@ -102,6 +102,18 @@ def test_fused_rejects_unsupported_experts():
         access(cfg, st, cl, sa, jnp.arange(1, 9, dtype=U32))
 
 
+def test_fused_rejects_pool_above_vmem_cap():
+    """Above the cap the fused backend refuses with a ValueError naming
+    it — never a compiler crash, never a quiet switch of engine."""
+    from repro.kernels.ops import FUSED_MAX_SLOTS
+    cfg = CacheConfig(n_buckets=2 * FUSED_MAX_SLOTS // 8, assoc=8,
+                      capacity=1024, backend="fused")
+    shapes = jax.eval_shape(lambda: make_cache(cfg, 8))
+    with pytest.raises(ValueError, match=f"FUSED_MAX_SLOTS={FUSED_MAX_SLOTS}"):
+        jax.eval_shape(lambda s, c, a: access(
+            cfg, s, c, a, jnp.arange(1, 9, dtype=U32)), *shapes)
+
+
 def test_unknown_backend_rejected():
     with pytest.raises(ValueError, match="backend"):
         CacheConfig(n_buckets=64, assoc=8, capacity=128, backend="mosaic")

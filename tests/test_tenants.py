@@ -144,15 +144,13 @@ def test_ranked_eviction_kernel_matches_ref_with_tenants():
     W, K, B, C = 16, 5, 24, 256
     for seed in range(3):
         rng = np.random.default_rng(seed)
-        size = np.zeros(C + W, np.float32)
+        size = np.zeros(C, np.uint32)
         live = rng.random(C) < 0.5
-        size[:C][live] = rng.integers(1, 9, live.sum())
-        size[C:] = size[:W]
-        ins = rng.integers(0, 1000, C + W).astype(np.float32)
-        last = rng.integers(0, 1000, C + W).astype(np.float32)
-        freq = rng.integers(1, 50, C + W).astype(np.float32)
-        tenant = rng.integers(0, 3, C).astype(np.float32)
-        tenant = np.concatenate([tenant, tenant[:W]])
+        size[live] = rng.integers(1, 9, live.sum())
+        ins = rng.integers(0, 1000, C).astype(np.uint32)
+        last = rng.integers(0, 1000, C).astype(np.uint32)
+        freq = rng.integers(1, 50, C).astype(np.uint32)
+        tenant = rng.integers(0, 3, C).astype(np.uint32)
         offs = rng.integers(0, C, B).astype(np.int32)
         choice = rng.integers(0, 2, B).astype(np.int32)
         must = rng.random(B) < 0.8
