@@ -1,0 +1,8 @@
+"""Mean host time per traced ``execute()`` call in its ``launch`` phase:
+the program's ``ditto.execute.launch`` spans (``bench/stages.py``), in ms."""
+
+from bench import stages
+
+
+def read(ctx):
+    return stages.phase_ms_per_call(ctx.traced, "launch")

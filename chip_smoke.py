@@ -95,7 +95,7 @@ def _phase(name, cache, keys, writes, exec_cfg=None, **plan):
     d = stats_delta(res.stats, cache.stats)
     ops = int(d.gets) + int(d.sets)
     hr = int(d.hits) / max(ops, 1)
-    first = sum(w["wall_s"] for w in res.windows if w["compiled"])
+    first = sum(w["wall_s"] for w in res.windows if w["compiles"])
     widths = sorted({w["width"] for w in res.windows})
     log(f"{name}: {ops} ops, hit rate {hr:.6f}, evictions "
         f"{int(d.evictions)}, n_cached {int(res.state.n_cached)}, "
@@ -182,8 +182,7 @@ def one_chip(sz: dict, on_tpu: bool) -> dict:
     fused_x = ExecConfig(backend="fused")
     if on_tpu:
         c0 = from_pool(pool)
-        fn, _ = _runner(merge_exec_config(cap_cfg, fused_x), False, True,
-                        None)
+        fn = _runner(merge_exec_config(cap_cfg, fused_x), False, True, None)
         text = fn.lower(c0.state, c0.clients, c0.stats,
                         jnp.asarray(keys), jnp.asarray(wr),
                         jnp.ones(keys.shape, jnp.uint32),

@@ -25,11 +25,19 @@ donation) ride in :class:`repro.core.types.ExecConfig`; the cache's own
 ``CacheConfig`` keeps only semantics.  Jitted segment runners are cached
 per (config, width, donation, interpret) so repeated calls pay zero
 retrace; measured per-segment step times feed back into the planner's
-cost model when a warm (already-compiled) runner produced them.
+cost model when the call compiled nothing.
+
+Every call is observable from inside: a ``ditto.execute`` profiler span
+over the whole call, with ``ditto.execute.launch`` / ``.wait`` /
+``.fetch`` children tiling each segment (see :func:`_phase`), and per
+segment ``windows[i]["phases_s"]`` (the same three durations),
+``["compiles"]`` and ``["compile_s"]`` (backend compiles that fired
+inside the call, from ``jax.monitoring``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import NamedTuple, Optional, Tuple
 
@@ -82,7 +90,8 @@ class ExecResult(NamedTuple):
     ops: np.ndarray            # i32[R]
     weights: np.ndarray        # f32[R, ...] expert-weight trajectory
     windows: Tuple[dict, ...]  # per-segment metrics: start/stop rows,
-                               # width, steps, fill, wall_s, us_per_call
+                               # width, steps, fill, wall_s, us_per_call,
+                               # phases_s, compiles, compile_s
     plan_s: float              # host planning time (seconds)
     wall_s: float              # execution wall time (seconds, excludes
                                # planning)
@@ -118,18 +127,64 @@ def clear_jit_cache() -> None:
     _JIT_CACHE.clear()
 
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+class _Compiles:
+    """Backend compiles (each a compile or a load from the persistent
+    cache) and their seconds, counted only while an :func:`execute` call
+    is in progress.  One instance listens for the whole process,
+    registered with ``jax.monitoring`` by the first call; a segment's
+    count is the difference across it (calls from several threads at
+    once would share it)."""
+
+    def __init__(self):
+        self.registered = False
+        self.active = 0
+        self.n = 0
+        self.seconds = 0.0
+
+    def __call__(self, event: str, duration: float, **_):
+        if self.active and event == _BACKEND_COMPILE:
+            self.n += 1
+            self.seconds += duration
+
+
+_COMPILES = _Compiles()
+
+
+@contextlib.contextmanager
+def _counting_compiles():
+    if not _COMPILES.registered:
+        jax.monitoring.register_event_duration_secs_listener(_COMPILES)
+        _COMPILES.registered = True
+    _COMPILES.active += 1
+    try:
+        yield
+    finally:
+        _COMPILES.active -= 1
+
+
+@contextlib.contextmanager
+def _phase(name: str, window: dict):
+    """One phase of a segment: a ``ditto.execute.<name>`` profiler span
+    (on the trace's clock, beside the device's operations) whose
+    host-clock duration is also added to ``window["phases_s"][name]``."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"ditto.execute.{name}"):
+        yield
+    phases = window.setdefault("phases_s", {})
+    phases[name] = phases.get(name, 0.0) + time.perf_counter() - t0
+
+
 def _runner(cfg: CacheConfig, grouped: bool, donate: bool,
             interpret: Optional[bool]):
-    """Jitted trace runner for one (config, mode) point, cached.
-
-    Returns ``(fn, warm)`` where ``warm`` is the set of argument-shape
-    keys this runner has already executed (jit recompiles per shape, so
-    warmth is per shape, not per runner — a first-seen shape's wall is a
-    compile and must not feed the planner's cost model)."""
+    """Jitted trace runner for one (config, mode) point, cached (jit
+    itself compiles once per argument shape)."""
     key = (cfg, grouped, donate, interpret)
-    hit = _JIT_CACHE.get(key)
-    if hit is not None:
-        return hit
+    fn = _JIT_CACHE.get(key)
+    if fn is not None:
+        return fn
     impl = _run_trace_grouped_impl if grouped else _run_trace_impl
 
     def run(state, clients, stats, keys, is_write, obj_size, tenant):
@@ -139,10 +194,38 @@ def _runner(cfg: CacheConfig, grouped: bool, donate: bool,
             return impl(cfg, state, clients, keys, is_write, obj_size,
                         tenant, stats)
 
-    fn = jax.jit(run, donate_argnums=(0, 1) if donate else ())
-    entry = (fn, set())
-    _JIT_CACHE[key] = entry
-    return entry
+    fn = _JIT_CACHE[key] = jax.jit(run,
+                                   donate_argnums=(0, 1) if donate else ())
+    return fn
+
+
+def _donate(exec_cfg: ExecConfig) -> bool:
+    if exec_cfg.donate is None:
+        return jax.default_backend() != "cpu"
+    return exec_cfg.donate
+
+
+def runner_hlo(cfg: CacheConfig, n_clients: int, rounds: int) -> str:
+    """The compiled HLO text, op metadata included, of the program that
+    :func:`execute` runs, with the default engine, for a sequential
+    segment of ``rounds`` rounds on a cache of ``cfg`` with ``n_clients``
+    lanes.  A TPU profiler trace names each device operation by its HLO
+    instruction only; this text gives each instruction its ``op_name``,
+    whose ``ditto.*`` component is the ``access_group`` stage it belongs
+    to (DESIGN.md §13).  Nothing is allocated on the device; with the
+    persistent compilation cache on, the program is loaded rather than
+    compiled again."""
+    exec_cfg = cfg.split()[1]
+    fn = _runner(merge_exec_config(cfg, exec_cfg), False, _donate(exec_cfg),
+                 exec_cfg.interpret)
+    trace = [jax.ShapeDtypeStruct((rounds, n_clients), dt)
+             for dt in (jnp.uint32, jnp.bool_, jnp.uint32, jnp.uint32)]
+    fresh = jax.eval_shape(lambda: make_cache(cfg, n_clients, 0))
+    # The calls after the first take the runner's own outputs: lower at
+    # those types, the program the steady state runs.
+    out = jax.eval_shape(fn, *fresh, *trace)
+    return fn.lower(out.state, out.clients, out.stats,
+                    *trace).compile().as_text()
 
 
 def _as_cache(cache) -> Cache:
@@ -222,41 +305,40 @@ def _execute_cluster(cluster, trace, *, plan, exec_cfg, is_write, sizes,
             f"trace width {L} not divisible by n_shards={cluster.n_shards}")
 
     key = ("cluster", cluster.local, cluster.n_shards, xc.route_factor)
-    hit = _JIT_CACHE.get(key)
-    if hit is None:
-        import functools
-        fn = jax.jit(functools.partial(
-            dm_execute, cluster.mesh, cluster.local,
-            route_factor=xc.route_factor))
-        hit = _JIT_CACHE[key] = (fn, set())
-    fn, warm = hit
-
-    args = dict(
-        is_write=None if is_write is None else jnp.asarray(
-            np.asarray(is_write, bool)),
-        obj_size=None if sizes is None else jnp.asarray(
-            np.asarray(sizes, np.uint32)),
-        tenant=None if tenants is None else jnp.asarray(
-            np.asarray(tenants, np.uint32)))
-    shape_key = (keys.shape, *(None if v is None else v.shape
-                               for v in args.values()))
-    was_warm = shape_key in warm
-    t0 = time.perf_counter()
-    dm, hits = fn(cluster.dm, jnp.asarray(keys),
-                  member=cluster.membership(), **args)
-    hits = np.asarray(jax.block_until_ready(hits), bool)
+    win = dict(start=0, stop=T, width=1)
+    n0, s0 = _COMPILES.n, _COMPILES.seconds
+    with _phase("launch", win):
+        fn = _JIT_CACHE.get(key)
+        if fn is None:
+            import functools
+            fn = _JIT_CACHE[key] = jax.jit(functools.partial(
+                dm_execute, cluster.mesh, cluster.local,
+                route_factor=xc.route_factor))
+        args = dict(
+            is_write=None if is_write is None else jnp.asarray(
+                np.asarray(is_write, bool)),
+            obj_size=None if sizes is None else jnp.asarray(
+                np.asarray(sizes, np.uint32)),
+            tenant=None if tenants is None else jnp.asarray(
+                np.asarray(tenants, np.uint32)))
+        t0 = time.perf_counter()
+        dm, hits = fn(cluster.dm, jnp.asarray(keys),
+                      member=cluster.membership(), **args)
+    with _phase("wait", win):
+        hits = jax.block_until_ready(hits)
     wall = time.perf_counter() - t0
-    warm.add(shape_key)
+    with _phase("fetch", win):
+        hits = np.asarray(hits, bool)
 
     new_cluster = cluster._replace(dm=dm)
     ops = (keys != 0).sum(axis=1).astype(np.int32)
     n_req = int(ops.sum())
-    windows = (dict(start=0, stop=T, width=1, n_steps=T, n_requests=n_req,
-                    fill=1.0, wall_s=wall,
-                    us_per_call=wall * 1e6 / max(n_req, 1),
-                    compiled=not was_warm),)
+    win.update(n_steps=T, n_requests=n_req, fill=1.0, wall_s=wall,
+               us_per_call=wall * 1e6 / max(n_req, 1),
+               compiles=_COMPILES.n - n0,
+               compile_s=_COMPILES.seconds - s0)
     return ExecResult(new_cluster, hits.sum(axis=1).astype(np.int32), ops,
-                      np.zeros((0,), np.float32), windows, 0.0, wall, None)
+                      np.zeros((0,), np.float32), (win,), 0.0, wall, None)
 
 
 def execute(cache, trace, *, plan=_UNSET, exec_cfg: ExecConfig | None = None,
@@ -285,10 +367,20 @@ def execute(cache, trace, *, plan=_UNSET, exec_cfg: ExecConfig | None = None,
     segments the trace's); totals in ``stats`` are order-free.
     """
     from repro.dm.cluster import Cluster
-    if isinstance(cache, Cluster):
-        return _execute_cluster(cache, trace, plan=plan, exec_cfg=exec_cfg,
-                                is_write=is_write, sizes=sizes,
-                                tenants=tenants)
+    with jax.profiler.TraceAnnotation("ditto.execute"), _counting_compiles():
+        if isinstance(cache, Cluster):
+            return _execute_cluster(cache, trace, plan=plan,
+                                    exec_cfg=exec_cfg, is_write=is_write,
+                                    sizes=sizes, tenants=tenants)
+        return _execute_cache(cache, trace, plan=plan, exec_cfg=exec_cfg,
+                              is_write=is_write, sizes=sizes,
+                              tenants=tenants, model=model)
+
+
+def _execute_cache(cache, trace, *, plan, exec_cfg, is_write, sizes, tenants,
+                   model) -> ExecResult:
+    """The single-pool branch of :func:`execute`: the planned segments,
+    each one jitted runner call (launch, wait, fetch)."""
     cache = _as_cache(cache)
     if exec_cfg is None:
         exec_cfg = cache.cfg.split()[1]
@@ -307,9 +399,7 @@ def execute(cache, trace, *, plan=_UNSET, exec_cfg: ExecConfig | None = None,
     sched, plan_s = _schedule_for(plan, keys, run_cfg, exec_cfg,
                                   is_write_np, sizes_np, tenants_np, model)
 
-    donate = exec_cfg.donate
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
+    donate = _donate(exec_cfg)
 
     state, clients, stats = cache.state, cache.clients, cache.stats
     hits_parts, ops_parts, w_parts, windows = [], [], [], []
@@ -325,48 +415,53 @@ def execute(cache, trace, *, plan=_UNSET, exec_cfg: ExecConfig | None = None,
         if rows <= 0:
             continue
         grouped = seg.width > 1
-        fn, warm = _runner(run_cfg, grouped, donate, exec_cfg.interpret)
-        if grouped:
-            gp = seg.plan
-            args = (jnp.asarray(gp.keys), jnp.asarray(gp.is_write),
-                    jnp.asarray(gp.sizes),
-                    jnp.zeros(gp.keys.shape, jnp.uint32)
-                    if gp.tenants is None else jnp.asarray(gp.tenants))
-            n_req = gp.n_scheduled
-            n_steps = gp.n_groups
-            fill = gp.fill
-        else:
-            k = jnp.asarray(keys[seg.start:seg.stop])
-            args = (k,
-                    _slice(is_write_np, jnp.zeros((rows, C), bool), seg),
-                    _slice(sizes_np, jnp.ones((rows, C), jnp.uint32), seg),
-                    _slice(tenants_np, jnp.zeros((rows, C), jnp.uint32),
-                           seg))
-            n_req = int((keys[seg.start:seg.stop] != 0).sum())
-            n_steps = rows
-            fill = 1.0
-        shape_key = tuple(a.shape for a in args)
-        was_warm = shape_key in warm
-        t0 = time.perf_counter()
-        res: TraceResult = fn(state, clients, stats, *args)
-        res = jax.block_until_ready(res)
+        win = dict(start=seg.start, stop=seg.stop, width=seg.width)
+        n0, s0 = _COMPILES.n, _COMPILES.seconds
+        with _phase("launch", win):
+            fn = _runner(run_cfg, grouped, donate, exec_cfg.interpret)
+            if grouped:
+                gp = seg.plan
+                args = (jnp.asarray(gp.keys), jnp.asarray(gp.is_write),
+                        jnp.asarray(gp.sizes),
+                        jnp.zeros(gp.keys.shape, jnp.uint32)
+                        if gp.tenants is None else jnp.asarray(gp.tenants))
+                n_req = gp.n_scheduled
+                n_steps = gp.n_groups
+                fill = gp.fill
+            else:
+                k = jnp.asarray(keys[seg.start:seg.stop])
+                args = (k,
+                        _slice(is_write_np, jnp.zeros((rows, C), bool), seg),
+                        _slice(sizes_np, jnp.ones((rows, C), jnp.uint32),
+                               seg),
+                        _slice(tenants_np, jnp.zeros((rows, C), jnp.uint32),
+                               seg))
+                n_req = int((keys[seg.start:seg.stop] != 0).sum())
+                n_steps = rows
+                fill = 1.0
+            t0 = time.perf_counter()
+            res: TraceResult = fn(state, clients, stats, *args)
+        with _phase("wait", win):
+            res = jax.block_until_ready(res)
         wall = time.perf_counter() - t0
-        warm.add(shape_key)
         wall_total += wall
-        state, clients, stats = res.state, res.clients, res.stats
-        hits_parts.append(np.asarray(res.hits))
-        ops_parts.append(np.asarray(res.ops))
-        w_parts.append(np.asarray(res.weights))
-        us_per_call = wall * 1e6 / max(n_req, 1)
-        windows.append(dict(
-            start=seg.start, stop=seg.stop, width=seg.width,
-            n_steps=n_steps, n_requests=n_req, fill=round(float(fill), 4),
-            wall_s=wall, us_per_call=us_per_call, compiled=not was_warm))
-        # Only warm timings teach the cost model (compiles would dwarf
-        # the signal and freeze the planner at G=1 forever).  Packing
-        # efficiency rides along so the planner's optimistic prune knows
-        # how much of each group was padding on THIS trace shape.
-        if model is not None and was_warm and n_steps > 0:
+        with _phase("fetch", win):
+            state, clients, stats = res.state, res.clients, res.stats
+            hits_parts.append(np.asarray(res.hits))
+            ops_parts.append(np.asarray(res.ops))
+            w_parts.append(np.asarray(res.weights))
+        compiles = _COMPILES.n - n0
+        win.update(n_steps=n_steps, n_requests=n_req,
+                   fill=round(float(fill), 4), wall_s=wall,
+                   us_per_call=wall * 1e6 / max(n_req, 1),
+                   compiles=compiles, compile_s=_COMPILES.seconds - s0)
+        windows.append(win)
+        # Only timings of calls that compiled nothing teach the cost model
+        # (compiles would dwarf the signal and freeze the planner at G=1
+        # forever).  Packing efficiency rides along so the planner's
+        # optimistic prune knows how much of each group was padding on
+        # THIS trace shape.
+        if model is not None and compiles == 0 and n_steps > 0:
             model.observe(seg.width, wall * 1e6 / n_steps,
                           eff=rows / (n_steps * seg.width))
 

@@ -12,6 +12,7 @@ imports this file.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,11 +128,17 @@ def _runner_args(cfg, lanes, rows, sharding):
 ])
 def test_trace_runner_compiles(one_chip, backend, cfg, lanes):
     """The whole sequential trace program execute() runs, compiled (not
-    interpreted) with donated state, at the chip smoke's sizes."""
+    interpreted) with donated state, at the chip smoke's sizes; the
+    access round's stage scopes survive into its op metadata."""
     run_cfg = merge_exec_config(cfg, ExecConfig(backend=backend))
-    fn, _ = _runner(run_cfg, False, True, False)
+    fn = _runner(run_cfg, False, True, False)
     compiled = fn.lower(*_runner_args(cfg, lanes, 64, one_chip)).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "fused")
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (backend == "fused")
+    scopes = {c for n in re.findall(r'op_name="([^"]*)"', text)
+              for c in n.split("/")}
+    for stage in ("probe", "hit_update", "evict", "apply", "account"):
+        assert f"ditto.{stage}" in scopes, stage
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16 * 2**30
 
