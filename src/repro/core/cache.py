@@ -105,18 +105,46 @@ def apply_penalties(weights: jnp.ndarray, penalties: jnp.ndarray,
     return w / jnp.sum(w, axis=-1, keepdims=True)
 
 
-def _first_winner(x: jnp.ndarray, valid: jnp.ndarray,
-                  domain: int) -> jnp.ndarray:
+def _first_winner(x: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """bool[B]: True for the first occurrence of each distinct value of
-    x in [0, domain) among valid lanes.  Scatter-min duplicate
-    resolution (cheaper than a sort on CPU/TPU): the earliest flattened
-    index — i.e. the earliest *round* — wins, matching sequential
-    precedence."""
-    B = x.shape[0]
-    pos = jnp.arange(B, dtype=I32)
-    tgt = jnp.where(valid, x.astype(I32), domain)
-    best = jnp.full((domain + 1,), B, I32).at[tgt].min(pos)
-    return valid & (best[jnp.where(valid, x.astype(I32), 0)] == pos)
+    x among valid lanes: the earliest flattened index — i.e. the earliest
+    *round* — wins, matching sequential precedence.  Each lane is
+    compared with every earlier one; XLA:TPU fuses the [B, B] compare into
+    its reduce, with no temporary.  A scatter-min into a table the size of
+    the values' domain would pay a pass over that table and, on XLA:TPU, a
+    serial loop over every update index."""
+    pos = jnp.arange(x.shape[0], dtype=I32)
+    earlier = (valid[None, :] & (x[None, :] == x[:, None])
+               & (pos[None, :] < pos[:, None]))                    # [B, B]
+    return valid & ~jnp.any(earlier, axis=1)
+
+
+def _set_live(cols: Tuple[jnp.ndarray, ...], idx: jnp.ndarray,
+              vals: Tuple[jnp.ndarray, ...], live: jnp.ndarray,
+              chunk: int) -> Tuple[jnp.ndarray, ...]:
+    """``c.at[idx].set(v)`` for each column ``c`` and its values ``v``,
+    at the ``live`` entries of ``idx`` only (distinct among themselves).
+
+    The live entries are taken in flat order, ``chunk`` at a time, and
+    each column is scattered over those ``chunk`` indices alone, in a loop
+    that runs ceil(live / chunk) times: none when nothing is live."""
+    n = idx.shape[0]
+    oob = cols[0].shape[0]
+    seen = jnp.cumsum(live.astype(I32))       # live entries up to and at i
+    n_live = seen[-1]
+
+    def write(carry):
+        t, cols = carry
+        p = t * chunk + jnp.arange(chunk, dtype=I32)      # live ranks
+        # Flat index of the live entry of rank p: the number of entries
+        # whose running count is still at most p.
+        src = jnp.minimum(jnp.sum(seen[None, :] <= p[:, None], axis=1), n - 1)
+        tgt = jnp.where(p < n_live, idx[src], oob)
+        return t + 1, tuple(c.at[tgt].set(v[src], mode="drop")
+                            for c, v in zip(cols, vals))
+
+    return jax.lax.while_loop(lambda carry: carry[0] * chunk < n_live,
+                              write, (jnp.int32(0), tuple(cols)))[1]
 
 
 def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
@@ -403,7 +431,7 @@ def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
         want_insert = miss & (insert_on_miss | is_write)
         # First-of-bucket dedup: duplicate keys share a bucket, so the first
         # inserting op per bucket is also the first per key.
-        winner = _first_winner(bucket, want_insert, cfg.n_buckets)
+        winner = _first_winner(bucket, want_insert)
         dropped = want_insert & ~winner
 
         free = (b_size == SIZE_EMPTY) | (is_hist & ~h_valid)     # [B, A]
@@ -563,7 +591,7 @@ def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
         # regardless of the block quota), so the reference and fused rankings
         # coincide rank for rank.
         victims = victims_2d.reshape(-1)                          # [B*V]
-        ev_winner = _first_winner(victims, victims >= 0, n_slots_total)
+        ev_winner = _first_winner(victims, victims >= 0)
         n_evict = jnp.sum(ev_winner).astype(I32)
         evicting = must_evict & jnp.any(take, axis=1)
 
@@ -658,12 +686,14 @@ def access_group(cfg: CacheConfig, state: CacheState, clients: ClientState,
         ext = ext.at[ii].set(prio.fresh_ext(ts_req, (B,)), mode="drop")
         vals = vals.at[ii].set(values, mode="drop")
 
-        ev_idx = jnp.where(ev_winner, victims, n_slots_total)
-        sizes3 = sizes3.at[ev_idx].set(
-            jnp.where(write_hist, U32(SIZE_HISTORY), U32(SIZE_EMPTY)), mode="drop")
-        ptr3 = ptr3.at[ev_idx].set(
-            jnp.where(write_hist, hist_ids, U32(0)), mode="drop")
-        ins_ts3 = ins_ts3.at[ev_idx].set(bmap, mode="drop")
+        # A pool at capacity evicts about one victim per insert, and a
+        # round's inserts are its misses: with chunks of a quarter of the
+        # requests, most rounds write their victims back in one pass.
+        sizes3, ptr3, ins_ts3 = _set_live(
+            (sizes3, ptr3, ins_ts3), victims,
+            (jnp.where(write_hist, U32(SIZE_HISTORY), U32(SIZE_EMPTY)),
+             jnp.where(write_hist, hist_ids, U32(0)), bmap),
+            ev_winner, chunk=max(8, B // 4))
 
         n_cached = (state.n_cached + jnp.sum(plain).astype(I32)
                     + jnp.sum(fallback_hist).astype(I32) - n_evict)

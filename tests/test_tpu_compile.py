@@ -121,18 +121,33 @@ def _runner_args(cfg, lanes, rows, sharding):
             shape(jax.eval_shape(init_stats)), *trace)
 
 
+@pytest.fixture(scope="module")
+def compiled_runner(one_chip):
+    """The sequential trace program execute() runs, compiled (not
+    interpreted) with donated state for 64 rounds, once per (backend,
+    config, lanes) in this module."""
+    done = {}
+
+    def compile_(backend, cfg, lanes):
+        if (backend, cfg, lanes) not in done:
+            run_cfg = merge_exec_config(cfg, ExecConfig(backend=backend))
+            fn = _runner(run_cfg, False, True, False)
+            done[backend, cfg, lanes] = fn.lower(
+                *_runner_args(cfg, lanes, 64, one_chip)).compile()
+        return done[backend, cfg, lanes]
+    return compile_
+
+
 @pytest.mark.parametrize("backend,cfg,lanes", [
     ("reference", SMOKE, 256),                       # the smoke's pool
     ("fused", CacheConfig(n_buckets=FUSED_MAX_SLOTS // 8, assoc=8,
                           capacity=2**13, experts=("lru", "lfu")), 64),
 ])
-def test_trace_runner_compiles(one_chip, backend, cfg, lanes):
-    """The whole sequential trace program execute() runs, compiled (not
-    interpreted) with donated state, at the chip smoke's sizes; the
-    access round's stage scopes survive into its op metadata."""
-    run_cfg = merge_exec_config(cfg, ExecConfig(backend=backend))
-    fn = _runner(run_cfg, False, True, False)
-    compiled = fn.lower(*_runner_args(cfg, lanes, 64, one_chip)).compile()
+def test_trace_runner_compiles(compiled_runner, backend, cfg, lanes):
+    """The whole sequential trace program execute() runs, compiled at the
+    chip smoke's sizes; the access round's stage scopes survive into its
+    op metadata."""
+    compiled = compiled_runner(backend, cfg, lanes)
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (backend == "fused")
     scopes = {c for n in re.findall(r'op_name="([^"]*)"', text)
@@ -141,6 +156,55 @@ def test_trace_runner_compiles(one_chip, backend, cfg, lanes):
         assert f"ditto.{stage}" in scopes, stage
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16 * 2**30
+
+
+# "%scatter.3 = u32[8388608]{0:T(1024)} scatter(%p.1, %t.2, %t.3), ...":
+# name, type, opcode, operands
+_HLO_INSTR = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([\w\-]+)\(([^)]*)\)")
+
+
+def _hlo_instrs(text):
+    """(name, type, opcode, operand names, op_name) of every instruction
+    of a compiled module, fused computations included."""
+    out = []
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            meta = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), re.sub(r"\{.*", "", m.group(2)),
+                        m.group(3), re.findall(r"%[\w.\-]+", m.group(4)),
+                        meta.group(1) if meta else ""))
+    return out
+
+
+def test_smoke_runner_victim_path_has_no_pool_sized_work(compiled_runner):
+    """At 256 lanes the round's 1,280 sampled victims and 256 bucket
+    inserts are deduplicated without a table the size of the pool or of
+    the bucket array, the eviction write-back scatters only chunks of the
+    live victims, and no pool column is copied more often than before:
+    XLA:TPU scatters cost time per update index, dropped ones included."""
+    lanes = 256
+    n_slots = SMOKE.n_buckets * SMOKE.assoc
+    victims = lanes * SMOKE.n_samples
+    text = compiled_runner("reference", SMOKE, lanes).as_text()
+    instrs = _hlo_instrs(text)
+    types = {name: ty for name, ty, *_ in instrs}
+    tables = (f"[{n_slots + 1}]", f"[{SMOKE.n_buckets + 1}]")
+    staged = [i for i in instrs
+              if {"ditto.evict", "ditto.apply"} & set(i[4].split("/"))]
+    assert any(op == "scatter" for _, _, op, _, _ in staged)
+    for name, ty, op, args, _ in staged:
+        assert not ty.endswith(tables), (name, ty)
+        if op == "scatter":
+            n_idx = int(re.match(r"\w+\[(\d+)", types[args[1]]).group(1))
+            assert n_idx < victims, (name, types[args[1]])
+    # Copies of a pool column of every kind, synchronous or async.  The
+    # runner before the victim compaction held three: async moves of a
+    # written column between memory spaces at the end of the scan body.
+    col_copy = re.compile(
+        rf"\s*(?:ROOT )?%[\w.\-]+ = \(?u32\[{n_slots}\][^=]*? (copy|copy-start)\(")
+    copies = [line.strip() for line in text.splitlines() if col_copy.match(line)]
+    assert len(copies) <= 3, copies
 
 
 def test_dm_execute_compiles_on_four_chips(topo):
