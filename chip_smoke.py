@@ -13,10 +13,14 @@ backend's VMEM cap (loaded full first) on both backends (bit-equal hits,
 OpStats and integer state required), and the same trace on this
 process's CPU backend with the reference engine.
 
-Four chips.  ``Cluster.make`` at 4x the one-chip pool, one one-chip pool
-per chip: pipelined ``Cluster.execute`` must be bit-equal to per-step
-routing on the same mesh; then a failure/recovery leg with hot-bucket
-replication must keep ``gets + sets + route_drops == issued``.
+Four chips.  ``Cluster.make`` at 4x the one-chip pool with YCSB's 1 KB
+records (``value_words=256``) and 256 lanes a shard, the four-chip
+benchmark's deployment: each shard on its own chip, no chip holding more
+than one shard after construction; pipelined ``Cluster.execute`` must be
+bit-equal to per-step routing on the same mesh (the pool is built twice,
+as two copies do not fit a chip); then a failure/recovery leg with
+hot-bucket replication must keep ``gets + sets + route_drops ==
+issued``.
 
 Every check that fails exits non-zero.  Times printed here are those of a
 smoke, not a benchmark.  The last line of standard output is the JSON
@@ -243,22 +247,36 @@ def four_chips(sz: dict) -> dict:
 
     S = 4
     cfg = CacheConfig(n_buckets=S * sz["n_buckets"], assoc=8,
-                      capacity=S * sz["capacity"], experts=("lru", "lfu"))
-    lanes = sz["lanes"] // S
-    cl = Cluster.make(cfg, S, lanes, seed=SEED)
-    log(f"cluster: {S} shards x {cl.local.n_buckets} buckets x "
-        f"{cfg.assoc} slots, global capacity {cfg.capacity}, "
-        f"{_pool_bytes(cl.state)} bytes of state, {S * lanes} lanes")
+                      capacity=S * sz["capacity"], experts=("lru", "lfu"),
+                      value_words=sz["value_words"])
+    lanes = sz["shard_lanes"]
     devs = jax.devices()[:S]
-    for name, x in zip(cl.state._fields, cl.state):
-        homes = sorted(s.device.id for s in x.addressable_shards)
-        if len(homes) != S or homes != sorted(d.id for d in devs):
-            raise SmokeFailure(f"state.{name} spans devices {homes}")
-        if any(s.data.shape[0] * S != x.shape[0]
-               for s in x.addressable_shards):
-            raise SmokeFailure(f"state.{name} is not split 1/{S} per chip")
+
+    def make():
+        cl = Cluster.make(cfg, S, lanes, seed=SEED)
+        for name, x in zip(cl.state._fields, cl.state):
+            homes = sorted(s.device.id for s in x.addressable_shards)
+            if len(homes) != S or homes != sorted(d.id for d in devs):
+                raise SmokeFailure(f"state.{name} spans devices {homes}")
+            if any(s.data.shape[0] * S != x.shape[0]
+                   for s in x.addressable_shards):
+                raise SmokeFailure(f"state.{name} is not split 1/{S} per "
+                                   "chip")
+        return cl
+
+    cl = make()
+    shard = _pool_bytes(cl.state) // S
+    log(f"cluster: {S} shards x {cl.local.n_buckets} buckets x "
+        f"{cfg.assoc} slots x {cfg.value_words} value words, global "
+        f"capacity {cfg.capacity}, {_pool_bytes(cl.state)} bytes of state "
+        f"({shard} a shard), {S * lanes} lanes")
     log(f"  check ok: every state array is split 1/{S} across {S} "
         "distinct chips")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in devs]
+    check(max(peaks) < 1.05 * shard + 2**28,
+          f"Cluster.make: peak {max(peaks)} bytes on the fullest chip, "
+          f"one shard {shard}")
 
     keys, wr = ycsb("A", sz["four_rounds"] * S * lanes, n_keys=sz["n_keys"],
                     seed=SEED + 3)
@@ -268,22 +286,32 @@ def four_chips(sz: dict) -> dict:
     hits = np.asarray(jax.block_until_ready(hits))
     log(f"pipelined Cluster.execute: {keys.size} ops; smoke, not a "
         f"benchmark: {time.perf_counter() - t0:.3f} s with compile")
-    step = jax.jit(functools.partial(_dm_access_impl, cl.mesh, cl.local))
-    member = cl.membership()
+
+    # One copy of the pool fits a chip, two do not: keep the pipelined
+    # run's account on the host (its records as a checksum), then build
+    # the same cluster again and drive it one step at a time.
+    def account(dm):
+        st = dm.state._replace(values=jnp.sum(dm.state.values,
+                                              dtype=jnp.uint32))
+        return jax.tree.map(np.asarray, (st, dm.clients, dm.stats))
+    want = account(piped.dm)
+    del piped, cl
+    cl = make()
+    step = jax.jit(functools.partial(_dm_access_impl, cl.mesh, cl.local),
+                   donate_argnums=0)
     dm, seq = cl.dm, []
     t0 = time.perf_counter()
     for t in range(keys.shape[0]):
         dm, h = step(dm, jnp.asarray(keys[t]), jnp.asarray(wr[t]),
-                     member=member)
+                     member=cl.routes)
         seq.append(np.asarray(h))
     log(f"per-step routing: {keys.shape[0]} calls; smoke, not a "
         f"benchmark: {time.perf_counter() - t0:.3f} s with compile")
     check(np.array_equal(np.stack(seq), hits),
           "pipelined hits == per-step hits")
-    for part in ("state", "clients", "stats"):
-        same = all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in
-                   zip(jax.tree.leaves(getattr(dm, part)),
-                       jax.tree.leaves(getattr(piped.dm, part))))
+    for part, a, b in zip(("state", "clients", "stats"), account(dm), want):
+        same = all(np.array_equal(x, y) for x, y in
+                   zip(jax.tree.leaves(a), jax.tree.leaves(b)))
         check(same, f"pipelined {part} == per-step {part}")
     hit_rate = float(hits.sum()) / max(int((keys != 0).sum()), 1)
 
@@ -291,7 +319,8 @@ def four_chips(sz: dict) -> dict:
     # recover with rewarm; every issued request is counted.
     gb = cfg.n_buckets
     bkt = np.asarray(bucket_of(hash_key(jnp.asarray(keys.ravel())), gb))
-    cl = piped.elect_replicas(np.bincount(bkt, minlength=gb), n_hot=4096)
+    cl = cl._replace(dm=dm).elect_replicas(np.bincount(bkt, minlength=gb),
+                                           n_hot=4096)
     before = cl.stats
     issued = 0
     legs = (("replicated", None), ("shard 1 lost", "fail"),
@@ -325,7 +354,8 @@ def four_chips(sz: dict) -> dict:
 
 FULL = dict(n_keys=N_KEYS, n_buckets=2**20, capacity=2**22, lanes=256,
             load_chunks=8, run_ops=2**20, cap_slots=None, cap_lanes=64,
-            cap_ops=2**15, four_rounds=64, leg_rounds=32)
+            cap_ops=2**15, four_rounds=64, leg_rounds=32, shard_lanes=256,
+            value_words=256)
 
 
 def main(argv=None) -> int:

@@ -228,6 +228,54 @@ def runner_hlo(cfg: CacheConfig, n_clients: int, rounds: int) -> str:
                     *trace).compile().as_text()
 
 
+def _trace_sharding(mesh):
+    """A [T, S * lanes] request tensor split by source shard: each chip
+    holds the lanes of its own shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.dm.sharded_cache import AXIS
+    return NamedSharding(mesh, P(None, AXIS))
+
+
+def cluster_runner_hlo(cfg: CacheConfig, n_shards: int,
+                       lanes_per_shard: int, rounds: int) -> str:
+    """:func:`runner_hlo` for a :class:`repro.dm.Cluster`: the compiled
+    HLO text, op metadata included, of the program :func:`execute` runs
+    for a call of ``rounds`` rounds of reads and writes on a cluster of
+    ``cfg`` (the GLOBAL pool) over ``n_shards`` shards with
+    ``lanes_per_shard`` lanes each, under the default execution knobs.
+    Besides the five ``access_group`` stages, its ``op_name`` components
+    name the router's own: ``ditto.route`` (packing requests per
+    destination, unpacking them, merging the answers back),
+    ``ditto.exchange`` (the ``all_to_all`` collectives) and
+    ``ditto.sync`` (the cross-shard weight sync).  Nothing is allocated
+    on the devices."""
+    import jax.sharding as jsh
+
+    from repro.dm.cluster import runner as cluster_runner
+    from repro.dm.sharded_cache import (AXIS, _dm_init_jit,
+                                        identity_membership)
+    xc = cfg.split()[1]
+    mesh, local, build = _dm_init_jit(cfg, n_shards, lanes_per_shard)
+    fn = cluster_runner(mesh, local, xc.route_factor, _donate(xc))
+    req = [jax.ShapeDtypeStruct((rounds, n_shards * lanes_per_shard), dt,
+                                sharding=_trace_sharding(mesh))
+           for dt in (jnp.uint32, jnp.bool_)]
+    def placed(tree, spec):
+        sh = jsh.NamedSharding(mesh, spec)
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            tree)
+    member = placed(jax.eval_shape(
+        lambda: identity_membership(n_shards, cfg.n_buckets)),
+        jsh.PartitionSpec())
+    # The calls after the first take the runner's own outputs, which
+    # keep the pool's layout: every array split over the shards.
+    dm = placed(jax.eval_shape(build), jsh.PartitionSpec(AXIS))
+    return fn.lower(dm, *req, None, None,
+                    member=member).compile().as_text()
+
+
 def _as_cache(cache) -> Cache:
     if isinstance(cache, Cache):
         return cache
@@ -286,10 +334,12 @@ def _execute_cluster(cluster, trace, *, plan, exec_cfg, is_write, sizes,
                      tenants) -> ExecResult:
     """Cluster branch of :func:`execute`: one pipelined, failover-aware
     ``dm_execute`` scan under the handle's membership (replica fan-out,
-    re-routes and dead-shard bounces all ride the routing maps).  The DM
+    re-routes and dead-shard bounces all ride the routing maps, which
+    stay on the chips: ``Cluster.routes``).  Each request tensor goes
+    straight to the chips of the shards whose lanes it holds.  The DM
     router packs per-destination groups itself, so host-side planning
     does not apply — ``plan`` must be left unset/None."""
-    from repro.dm.sharded_cache import dm_execute
+    from repro.dm.cluster import runner as cluster_runner
     if plan is not _UNSET and plan is not None:
         raise ValueError(
             "execute(Cluster, ...) runs the pipelined DM scan — the "
@@ -304,26 +354,17 @@ def _execute_cluster(cluster, trace, *, plan, exec_cfg, is_write, sizes,
         raise ValueError(
             f"trace width {L} not divisible by n_shards={cluster.n_shards}")
 
-    key = ("cluster", cluster.local, cluster.n_shards, xc.route_factor)
     win = dict(start=0, stop=T, width=1)
     n0, s0 = _COMPILES.n, _COMPILES.seconds
     with _phase("launch", win):
-        fn = _JIT_CACHE.get(key)
-        if fn is None:
-            import functools
-            fn = _JIT_CACHE[key] = jax.jit(functools.partial(
-                dm_execute, cluster.mesh, cluster.local,
-                route_factor=xc.route_factor))
-        args = dict(
-            is_write=None if is_write is None else jnp.asarray(
-                np.asarray(is_write, bool)),
-            obj_size=None if sizes is None else jnp.asarray(
-                np.asarray(sizes, np.uint32)),
-            tenant=None if tenants is None else jnp.asarray(
-                np.asarray(tenants, np.uint32)))
+        fn = cluster_runner(cluster.mesh, cluster.local, xc.route_factor,
+                            _donate(xc))
+        on_chips = lambda x, dt: None if x is None else jax.device_put(
+            np.asarray(x, dt), _trace_sharding(cluster.mesh))
         t0 = time.perf_counter()
-        dm, hits = fn(cluster.dm, jnp.asarray(keys),
-                      member=cluster.membership(), **args)
+        dm, hits = fn(cluster.dm, on_chips(keys, np.uint32),
+                      on_chips(is_write, bool), on_chips(sizes, np.uint32),
+                      on_chips(tenants, np.uint32), member=cluster.routes)
     with _phase("wait", win):
         hits = jax.block_until_ready(hits)
     wall = time.perf_counter() - t0

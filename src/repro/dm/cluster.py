@@ -24,21 +24,25 @@ Liveness is two views, on purpose:
 
 Everything `membership()` computes is a pure function of
 (alive, routed, replicas), so reruns of a seeded failure timeline route
-identically — the determinism the failover tests pin down.
+identically — the determinism the failover tests pin down.  The handle
+keeps those maps on its chips (``routes``), made once per membership by
+the mutators that change it; a steady-state `execute` moves only its
+requests and answers between host and chips.
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hashing import splitmix32
 from repro.core.types import CacheConfig
-from repro.dm.sharded_cache import (DMCache, Membership, _dm_make_impl,
-                                    dm_execute)
+from repro.dm.sharded_cache import (AXIS, DMCache, Membership,
+                                    _dm_make_impl, dm_execute)
 
 __all__ = ["Cluster", "with_capacity", "with_tenant_budgets", "with_lanes",
            "mark_failed", "replica_map"]
@@ -70,6 +74,9 @@ class Cluster(NamedTuple):
     replicas: np.ndarray           # i32[global_buckets] secondary shard
                                    # per bucket; n_shards = unreplicated
     seed: int
+    routes: Membership = None      # membership(), replicated on the
+                                   # chips; remade by every mutator of
+                                   # (alive, routed, replicas)
 
     # ------------------------------------------------------------------
     # Construction
@@ -81,14 +88,16 @@ class Cluster(NamedTuple):
         """Build a sharded cache cluster.  ``cfg`` describes the GLOBAL
         pool; each shard runs a local core cache over 1/n_shards of the
         buckets/capacity (exactly the deprecated ``dm_make`` triple,
-        plus the membership the legacy surface never modeled)."""
+        plus the membership the legacy surface never modeled).  Each
+        shard is built on its own chip; no device ever holds more than
+        one shard's state."""
         mesh, dm, local = _dm_make_impl(cfg, n_shards, lanes_per_shard,
                                         seed)
         return cls(mesh=mesh, cfg=cfg, local=local, dm=dm,
                    n_shards=n_shards, lanes_per_shard=lanes_per_shard,
                    alive=(True,) * n_shards, routed=(True,) * n_shards,
                    replicas=np.full((cfg.n_buckets,), n_shards, np.int32),
-                   seed=seed)
+                   seed=seed)._rerouted()
 
     # ------------------------------------------------------------------
     # Handle-shaped views (so ExecResult's delegating properties work on
@@ -139,6 +148,12 @@ class Cluster(NamedTuple):
                           replica=jnp.asarray(rep),
                           serving=jnp.asarray(np.asarray(self.alive, bool)))
 
+    def _rerouted(self) -> "Cluster":
+        """This handle with ``routes`` remade from its membership and
+        placed, replicated, on its chips."""
+        return self._replace(routes=jax.device_put(
+            self.membership(), NamedSharding(self.mesh, P())))
+
     def replica_map(self) -> np.ndarray:
         """i32[global_buckets] secondary shard per bucket (n_shards =
         unreplicated).  A copy — the handle stays immutable."""
@@ -158,7 +173,7 @@ class Cluster(NamedTuple):
                 f"got {rep.shape}")
         if ((rep < 0) | (rep > self.n_shards)).any():
             raise ValueError("replica shard ids must be in [0, n_shards]")
-        return self._replace(replicas=rep.copy())
+        return self._replace(replicas=rep.copy())._rerouted()
 
     def elect_replicas(self, loads, n_hot: int) -> "Cluster":
         """Elect replica sets for the ``n_hot`` hottest buckets from a
@@ -188,7 +203,7 @@ class Cluster(NamedTuple):
             best = np.argmax(sc, axis=1).astype(np.int32)
             ok = sc[np.arange(hot.size), best] >= 0
             rep[hot[ok]] = best[ok]
-        return self._replace(replicas=rep)
+        return self._replace(replicas=rep)._rerouted()
 
     # ------------------------------------------------------------------
     # Elastic resize (the legacy resize surface, handle-shaped)
@@ -247,7 +262,7 @@ class Cluster(NamedTuple):
         alive[k] = False
         return self._replace(
             dm=fail_wipe_shard(self.mesh, self.local, self.dm, k),
-            alive=tuple(alive))
+            alive=tuple(alive))._rerouted()
 
     def mark_failed(self, k: int) -> "Cluster":
         """Membership action on detection: stop routing to shard k.
@@ -257,7 +272,7 @@ class Cluster(NamedTuple):
             raise ValueError(f"shard {k} out of range")
         routed = list(self.routed)
         routed[k] = False
-        return self._replace(routed=tuple(routed))
+        return self._replace(routed=tuple(routed))._rerouted()
 
     def recover(self, k: int, *, rewarm: bool = True,
                 max_objects: int = 512):
@@ -274,7 +289,8 @@ class Cluster(NamedTuple):
         routed = list(self.routed)
         alive[k] = True
         routed[k] = True
-        c = self._replace(alive=tuple(alive), routed=tuple(routed))
+        c = self._replace(alive=tuple(alive),
+                          routed=tuple(routed))._rerouted()
         if not rewarm:
             return c, ResizeReport(0, 0, 0, 0)
         dm, report = rewarm_shard(c.mesh, c.local, c.dm, k,
@@ -291,22 +307,40 @@ class Cluster(NamedTuple):
         through the pipelined DM driver under this membership.  Returns
         (cluster, hits).  The driver is jitted and cached per
         (mesh, local, route_factor) — membership rides as traced arrays,
-        so failover/replica changes never recompile."""
-        import functools
-
-        import jax
-        key = (self.mesh, self.local, route_factor)
-        fn = _EXEC_CACHE.get(key)
-        if fn is None:
-            fn = _EXEC_CACHE[key] = jax.jit(functools.partial(
-                dm_execute, self.mesh, self.local,
-                route_factor=route_factor))
-        dm, hits = fn(self.dm, keys, is_write=is_write, obj_size=obj_size,
-                      tenant=tenant, member=self.membership())
+        so failover/replica changes never recompile.  On an accelerator
+        the input handle's state is donated: it is dead after the call."""
+        fn = runner(self.mesh, self.local, route_factor,
+                    jax.default_backend() != "cpu")
+        dm, hits = fn(self.dm, keys, is_write, obj_size, tenant,
+                      member=self.routes)
         return self._replace(dm=dm), hits
 
 
 _EXEC_CACHE: dict = {}
+
+
+def runner(mesh: Mesh, local: CacheConfig, route_factor: int,
+           donate: bool):
+    """The jitted ``dm_execute`` for one cluster shape, cached: called as
+    ``fn(dm, keys, is_write, obj_size, tenant, member=routes)``; its
+    compiled module is ``jit_cluster_run``.  The pool comes back split
+    over the shards as ``Cluster.make`` lays it out, the hits whole on
+    every chip.  With ``donate`` the input ``dm`` is donated, so a step
+    holds one copy of the pool on each chip."""
+    key = (mesh, local, route_factor, donate)
+    fn = _EXEC_CACHE.get(key)
+    if fn is None:
+        def cluster_run(dm, keys, is_write, obj_size, tenant, *, member):
+            return dm_execute(mesh, local, dm, keys, is_write, obj_size,
+                              tenant, route_factor=route_factor,
+                              member=member)
+        # Outputs in the layout Cluster.make builds: the steady state runs
+        # one program whatever the inputs' layout was.
+        fn = _EXEC_CACHE[key] = jax.jit(
+            cluster_run, donate_argnums=(0,) if donate else (),
+            out_shardings=(NamedSharding(mesh, P(AXIS)),
+                           NamedSharding(mesh, P())))
+    return fn
 
 
 # ----------------------------------------------------------------------

@@ -1,23 +1,28 @@
 """Disaggregated-memory runtime: the Ditto cache sharded over a device mesh.
 
-Mapping (DESIGN.md §2): every device hosts (a) one shard of the memory pool
-— a contiguous bucket range of the sample-friendly table — and (b) a group
-of client lanes. Clients hash keys to the owning pool shard and route the
-batch with an `all_to_all` (the RDMA network analogue); each shard then
-executes the ordinary client-centric `access()` against its local bucket
-slice; results route back by reversing the exchange.
+Mapping (DESIGN.md §2, §14): every device hosts (a) one shard of the
+memory pool — a contiguous bucket range of the sample-friendly table — and
+(b) the client lanes that serve it: the compute pool's clients as this
+memory node sees them, one lane for each request position it executes.
+Clients hash keys to the owning pool shard and route the batch with an
+`all_to_all` (the RDMA network analogue); each shard then executes the
+ordinary client-centric `access()` against its local bucket slice;
+results route back by reversing the exchange.  Each shard is built on its
+own device (`_dm_init_jit`).
 
 Decoupling survives the co-location: pool capacity is a per-shard runtime
 scalar (grow/shrink without touching data) and the client-lane count per
 device is a batch width (compute elasticity without touching the pool).
 
-The lazy weight update (§4.3.2) becomes a periodic `psum` of the batched
-penalty aggregates across all shards — the "RPC to the MN controller".
+The lazy weight update (§4.3.2) becomes a periodic exchange of the batched
+penalty aggregates across all shards, added in shard order — the "RPC to
+the MN controller".
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -71,32 +76,46 @@ def identity_membership(n_shards: int, global_buckets: int) -> Membership:
         serving=jnp.ones((n_shards,), bool))
 
 
-def _pad_clients(clients: ClientState, n: int) -> ClientState:
-    """Present a shard's client lanes as n request lanes (q-padded).
-
-    Replicating lanes verbatim would duplicate their `rng` streams —
-    padded lanes would fold in the same key and produce identical sample
-    offsets / expert choices (correlated evictions). The lane index is
-    folded into every padded-tail key so each presented lane draws an
-    independent stream; the original lanes keep their stored keys."""
-    lanes = clients.fc_slot.shape[0]
-
-    def pad(x):
-        reps = -(-n // x.shape[0])
-        return jnp.concatenate([x] * reps, axis=0)[:n]
-
-    padded = jax.tree.map(pad, clients)
-    idx = jnp.arange(n, dtype=jnp.uint32)
-    folded = jax.vmap(jax.random.fold_in)(padded.rng, idx)
-    rng = jnp.where((idx < lanes)[:, None], padded.rng, folded)
-    return padded._replace(rng=rng)
+def _positions(clients: ClientState, n_shards: int, q: int) -> ClientState:
+    """The client lanes a shard's ``n_shards * q`` request positions run
+    on.  A memory node holds ``held`` lanes for each source shard (the
+    compute pool's clients as this node sees them, DESIGN.md §14):
+    position ``src * q + r`` is lane ``r`` of source ``src``'s block, so
+    every position carries its own persistent FC cache, penalty buffers,
+    local weights and rng stream.  With ``q == held`` (every cluster of
+    up to four shards at the default route factor) this is the identity."""
+    held = clients.fc_slot.shape[0] // n_shards
+    if held == q:
+        return clients
+    return jax.tree.map(
+        lambda x: x.reshape((n_shards, held) + x.shape[1:])[:, :q].reshape(
+            (n_shards * q,) + x.shape[1:]), clients)
 
 
-def _unpad_clients(orig: ClientState, padded: ClientState,
-                   lanes: int) -> ClientState:
-    def cut(o, p):
-        return p[:lanes] if p.shape[0] >= lanes else o
-    return jax.tree.map(cut, orig, padded)
+def _merge_positions(clients: ClientState, served: ClientState,
+                     n_shards: int, q: int) -> ClientState:
+    """Write the positions' advanced lanes back into the held lanes
+    (inverse of :func:`_positions`)."""
+    held = clients.fc_slot.shape[0] // n_shards
+    if held == q:
+        return served
+    return jax.tree.map(
+        lambda x, y: x.reshape((n_shards, held) + x.shape[1:]).at[:, :q].set(
+            y.reshape((n_shards, q) + y.shape[1:])).reshape(x.shape),
+        clients, served)
+
+
+def _check_lanes(dm: "DMCache", n_shards: int, lanes: int, q: int) -> None:
+    """A trace of ``lanes`` lanes a shard routes up to ``q`` requests from
+    each source to each node; the cluster must hold that many lanes a
+    source at every node."""
+    held = dm.clients.fc_slot.shape[0] // (n_shards * n_shards)
+    if q > held:
+        raise ValueError(
+            f"a trace of {lanes} lanes a shard routes up to {q} requests "
+            f"from each source to each shard, but the cluster holds "
+            f"{held} lanes a source (lanes_per_shard); rebuild it or "
+            "resize its lanes to at least that width")
 
 
 def _mesh(n: int) -> Mesh:
@@ -118,21 +137,33 @@ def dm_make(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
     return _dm_make_impl(cfg, n_shards, lanes_per_shard, seed)
 
 
-def _dm_make_impl(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
-                  seed: int = 0) -> Tuple[Mesh, "DMCache", CacheConfig]:
-    """Build a sharded cache. cfg describes the GLOBAL pool; each shard
-    runs a local core cache over 1/n_shards of the buckets/capacity."""
-    assert cfg.n_buckets % n_shards == 0
-    assert cfg.capacity % n_shards == 0
-    assert cfg.capacity_blocks % n_shards == 0
-    local = dataclasses.replace(
+def _local_config(cfg: CacheConfig, n_shards: int) -> CacheConfig:
+    """One shard's slice of the GLOBAL pool ``cfg``: 1/n_shards of its
+    buckets, capacity, byte budget and history length."""
+    for name in ("n_buckets", "capacity", "capacity_blocks"):
+        size = getattr(cfg, name)
+        if size % n_shards:
+            raise ValueError(
+                f"a pool of {name}={size} does not split over {n_shards} "
+                f"shards: {name} must be a multiple of n_shards")
+    return dataclasses.replace(
         cfg, n_buckets=cfg.n_buckets // n_shards,
         capacity=cfg.capacity // n_shards,
         capacity_blocks=cfg.capacity_blocks // n_shards,
         hist_len=cfg.history_len // n_shards)
-    mesh = _mesh(n_shards)
-    state = init_cache(cfg)  # global arrays; shard by slot ranges
-    # Per-shard scalars (n_cached, hist_ctr, ...) must exist per shard:
+
+
+def _dm_init(cfg: CacheConfig, local: CacheConfig, n_shards: int,
+             lanes_per_shard: int, seed: int) -> "DMCache":
+    """A fresh cluster at its global shapes: the pool's slot arrays (shard
+    s owns slots [s * n, (s + 1) * n)), one row of every per-shard scalar
+    a shard, ``n_shards * n_shards * lanes_per_shard`` client lanes (each
+    node holds ``lanes_per_shard`` for every source shard, see
+    :func:`_positions`) and per-shard counters.  Traced under a jit whose
+    outputs are sharded over the pool axis, so each shard is built on
+    its own device."""
+    state = init_cache(cfg)
+
     def rep(x):
         return jnp.broadcast_to(x[None], (n_shards,) + x.shape)
     state = state._replace(
@@ -146,17 +177,32 @@ def _dm_make_impl(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
         # Exact per-shard split (column sums == the global budgets).
         tenant_budget=jnp.asarray(
             split_tenant_budgets(cfg.tenant_budgets, n_shards)))
-    clients = init_clients(cfg, n_shards * lanes_per_shard, seed)
-
-    sh_slot = NamedSharding(mesh, P(AXIS))
-    sh_scalar = NamedSharding(mesh, P(AXIS))
-
-    state = jax.tree.map(lambda x: jax.device_put(x, sh_slot), state)
-    clients = jax.tree.map(lambda x: jax.device_put(x, sh_slot), clients)
+    clients = init_clients(cfg, n_shards * n_shards * lanes_per_shard, seed)
     stats = jax.tree.map(lambda x: jnp.zeros((n_shards,), x.dtype),
                          init_stats())
-    stats = jax.tree.map(lambda x: jax.device_put(x, sh_scalar), stats)
-    return mesh, DMCache(state, clients, stats), local
+    return DMCache(state, clients, stats)
+
+
+def _dm_init_jit(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
+                seed: int = 0):
+    """(mesh, local config, the jitted function that makes the cluster's
+    DMCache with every shard on its own device)."""
+    local = _local_config(cfg, n_shards)
+    mesh = _mesh(n_shards)
+    build = jax.jit(functools.partial(_dm_init, cfg, local, n_shards,
+                                      lanes_per_shard, seed),
+                    out_shardings=NamedSharding(mesh, P(AXIS)))
+    return mesh, local, build
+
+
+def _dm_make_impl(cfg: CacheConfig, n_shards: int, lanes_per_shard: int,
+                  seed: int = 0) -> Tuple[Mesh, "DMCache", CacheConfig]:
+    """Build a sharded cache. cfg describes the GLOBAL pool; each shard
+    runs a local core cache over 1/n_shards of the buckets/capacity.
+    Every shard's arrays are made on its own device: no device ever
+    holds more than one shard's state."""
+    mesh, local, build = _dm_init_jit(cfg, n_shards, lanes_per_shard, seed)
+    return mesh, build(), local
 
 
 def _squeeze_shard(state: CacheState, stats: OpStats):
@@ -311,16 +357,38 @@ def _back_merge(hit_back, src_slot, lanes: int):
         jnp.where(valid, hit_back, False).reshape(-1))
 
 
+def _pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` summed over its leading (lane) axis in a fixed pairwise tree:
+    zero-padded to a power of two, then adjacent rows added level by
+    level.  Its bits do not depend on how a backend orders a reduction."""
+    n = x.shape[0]
+    width = 1 << max(n - 1, 0).bit_length()
+    x = jnp.concatenate([x, jnp.zeros((width - n,) + x.shape[1:], x.dtype)])
+    while x.shape[0] > 1:
+        x = x.reshape((-1, 2) + x.shape[1:])
+        x = x[:, 0] + x[:, 1]
+    return x[0]
+
+
 def _sync_weights(local_cfg: CacheConfig, state: CacheState,
                   clients: ClientState):
-    """Lazy weight update: periodic psum of penalty aggregates — the
-    'RPC to the MN controller' (§4.3.2), shared by both DM drivers."""
+    """Lazy weight update: when any shard's lanes hold ``sync_period``
+    buffered regrets between them, every shard applies the penalties
+    buffered on all shards to its weights and every lane adopts them —
+    the 'RPC to the MN controller' (§4.3.2), shared by ``dm_access``
+    and ``dm_execute``."""
     tot = jnp.sum(clients.penalty_cnt)
     # All shards agree on the sync decision (consistent global weights).
     do_sync = jax.lax.pmax((tot >= local_cfg.sync_period).astype(
         jnp.int32), AXIS) > 0
-    pen = jnp.sum(clients.penalty_acc, axis=0)
-    pen_global = jax.lax.psum(jnp.where(do_sync, pen, 0.0), AXIS)
+    pen = _pairwise_sum(clients.penalty_acc)
+    # Every shard adds the shards' sums in shard order, so the global
+    # penalty has the same bits on every shard and under any collective
+    # schedule (a psum's order of addition is the runtime's).
+    parts = jax.lax.all_gather(jnp.where(do_sync, pen, 0.0), AXIS)
+    pen_global = parts[0]
+    for part in parts[1:]:
+        pen_global = pen_global + part
     lam = jnp.float32(local_cfg.learning_rate)
     # Shared clamp-then-normalize update (core/cache.py): global
     # weights sum to exactly 1 on the DM path too.
@@ -414,6 +482,7 @@ def _dm_access_impl(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
         member = identity_membership(n_shards,
                                      local_cfg.n_buckets * n_shards)
 
+    _check_lanes(dm, n_shards, lanes, q)
     route_one = _make_route_one(local_cfg, n_shards, lanes, q)
 
     def step(state, clients, stats, keys_l, write_l, size_l, ten_l, mem):
@@ -423,33 +492,40 @@ def _dm_access_impl(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
         # tenant (bits 10+), so sizes are clipped to the engine's own
         # 8-bit clamp (the access path clips identically — bit-identical
         # results).
-        size_c = jnp.clip(size_l, 1, 254).astype(jnp.uint32)
-        packed, src_slot, n_drop, n_rep_drop = jax.vmap(
-            route_one, in_axes=(0, 0, 0, 0, None))(
-            keys_l, write_l, size_c, ten_l, mem)           # [G, S, q, 2]
+        with jax.named_scope("ditto.route"):
+            size_c = jnp.clip(size_l, 1, 254).astype(jnp.uint32)
+            packed, src_slot, n_drop, n_rep_drop = jax.vmap(
+                route_one, in_axes=(0, 0, 0, 0, None))(
+                keys_l, write_l, size_c, ten_l, mem)       # [G, S, q, 2]
         # --- the network: ONE exchange ships each destination's whole
         # [G, q] request group (RDMA doorbell-batching analogue) ---------
-        precv = jax.lax.all_to_all(packed, AXIS, 1, 1, tiled=True)
-        recv, wrecv, zrecv, nrecv, shrecv = _unpack_recv(precv, n_shards, q)
-        recv, n_bnc, n_bnc_sh = _bounce_dead(mem, recv, shrecv)
+        with jax.named_scope("ditto.exchange"):
+            precv = jax.lax.all_to_all(packed, AXIS, 1, 1, tiled=True)
+        with jax.named_scope("ditto.route"):
+            recv, wrecv, zrecv, nrecv, shrecv = _unpack_recv(
+                precv, n_shards, q)
+            recv, n_bnc, n_bnc_sh = _bounce_dead(mem, recv, shrecv)
 
         # --- memory-pool side: one widened client-centric group step ----
-        state, clients2, stats, res = access_group(
-            local_cfg, state, _pad_clients(clients, n_shards * q), stats,
+        state, served, stats, res = access_group(
+            local_cfg, state, _positions(clients, n_shards, q), stats,
             recv, is_write=wrecv, obj_size=zrecv, tenant=nrecv,
             shadow=shrecv)
+        clients = _merge_positions(clients, served, n_shards, q)
         stats = stats_add(stats, route_drops=jnp.sum(n_drop) + n_bnc,
                           replica_drops=jnp.sum(n_rep_drop) + n_bnc_sh)
 
         # --- route replies back + merge hit masks -----------------------
-        hits = jax.vmap(
-            lambda hb, ss: _back_merge(hb, ss, lanes))(
-            jax.lax.all_to_all(res.hit.reshape(G, n_shards, q),
-                               AXIS, 1, 1, tiled=True), src_slot)
+        with jax.named_scope("ditto.exchange"):
+            back = jax.lax.all_to_all(res.hit.reshape(G, n_shards, q),
+                                      AXIS, 1, 1, tiled=True)
+        with jax.named_scope("ditto.route"):
+            hits = jax.vmap(lambda hb, ss: _back_merge(hb, ss, lanes))(
+                back, src_slot)
 
-        # --- lazy weight update: periodic psum of penalty aggregates ----
-        clients = _unpad_clients(clients, clients2, lanes)
-        state, clients = _sync_weights(local_cfg, state, clients)
+        # --- lazy weight update: periodic sync of penalty aggregates ----
+        with jax.named_scope("ditto.sync"):
+            state, clients = _sync_weights(local_cfg, state, clients)
         state, stats = _expand_shard(state, stats)
         return state, clients, stats, hits
 
@@ -530,27 +606,30 @@ def dm_execute(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
         return dm, (jnp.zeros((0, keys.shape[2]), bool) if flat
                     else jnp.zeros(keys.shape, bool))
 
+    _check_lanes(dm, n_shards, lanes, q)
     route_one = _make_route_one(local_cfg, n_shards, lanes, q)
 
     def run(state, clients, stats, keys_l, write_l, size_l, ten_l, mem):
         state, stats = _squeeze_shard(state, stats)
-        size_c = jnp.clip(size_l, 1, 254).astype(jnp.uint32)
         # Route EVERY group up front — routing reads only the keys and
         # the (step-constant) membership, so this is exact, and it is
         # what the pipeline overlaps.
-        packed, src_slot, n_drop, n_rep_drop = jax.vmap(
-            jax.vmap(route_one, in_axes=(0, 0, 0, 0, None)),
-            in_axes=(0, 0, 0, 0, None))(
-            keys_l, write_l, size_c, ten_l, mem)     # [NG, G, S, q, 2]
-        # Summed once == added once per step (integer counter).
-        stats = stats_add(stats, route_drops=jnp.sum(n_drop),
-                          replica_drops=jnp.sum(n_rep_drop))
+        with jax.named_scope("ditto.route"):
+            size_c = jnp.clip(size_l, 1, 254).astype(jnp.uint32)
+            packed, src_slot, n_drop, n_rep_drop = jax.vmap(
+                jax.vmap(route_one, in_axes=(0, 0, 0, 0, None)),
+                in_axes=(0, 0, 0, 0, None))(
+                keys_l, write_l, size_c, ten_l, mem)     # [NG, G, S, q, 2]
+            # Summed once == added once per step (integer counter).
+            stats = stats_add(stats, route_drops=jnp.sum(n_drop),
+                              replica_drops=jnp.sum(n_rep_drop))
+            # Scan inputs are each step's NEXT group (wrapped tail: the
+            # last step re-sends group 0 and discards the reply).
+            nxt = jnp.concatenate([packed[1:], packed[:1]], axis=0)
 
         # Prologue: group 0's exchange fills the first recv buffer.
-        recv0 = jax.lax.all_to_all(packed[0], AXIS, 1, 1, tiled=True)
-        # Scan inputs are each step's NEXT group (wrapped tail: the last
-        # step re-sends group 0 and discards the reply).
-        nxt = jnp.concatenate([packed[1:], packed[:1]], axis=0)
+        with jax.named_scope("ditto.exchange"):
+            recv0 = jax.lax.all_to_all(packed[0], AXIS, 1, 1, tiled=True)
 
         def body(carry, xs):
             state, clients, stats, precv = carry
@@ -559,22 +638,28 @@ def dm_execute(mesh: Mesh, local_cfg: CacheConfig, dm: DMCache,
             # depends only on pre-routed keys, never on the carry, so
             # the scheduler can run it concurrently with this group's
             # access_group (the double-buffer overlap).
-            precv_next = jax.lax.all_to_all(pnxt, AXIS, 1, 1, tiled=True)
-            recv, wrecv, zrecv, nrecv, shrecv = _unpack_recv(
-                precv, n_shards, q)
-            recv, n_bnc, n_bnc_sh = _bounce_dead(mem, recv, shrecv)
-            stats = stats_add(stats, route_drops=n_bnc,
-                              replica_drops=n_bnc_sh)
-            state, clients2, stats, res = access_group(
-                local_cfg, state, _pad_clients(clients, n_shards * q),
+            with jax.named_scope("ditto.exchange"):
+                precv_next = jax.lax.all_to_all(pnxt, AXIS, 1, 1,
+                                                tiled=True)
+            with jax.named_scope("ditto.route"):
+                recv, wrecv, zrecv, nrecv, shrecv = _unpack_recv(
+                    precv, n_shards, q)
+                recv, n_bnc, n_bnc_sh = _bounce_dead(mem, recv, shrecv)
+                stats = stats_add(stats, route_drops=n_bnc,
+                                  replica_drops=n_bnc_sh)
+            state, served, stats, res = access_group(
+                local_cfg, state, _positions(clients, n_shards, q),
                 stats, recv, is_write=wrecv, obj_size=zrecv, tenant=nrecv,
                 shadow=shrecv)
-            hits = jax.vmap(
-                lambda hb, s: _back_merge(hb, s, lanes))(
-                jax.lax.all_to_all(res.hit.reshape(G, n_shards, q),
-                                   AXIS, 1, 1, tiled=True), ss)
-            clients = _unpad_clients(clients, clients2, lanes)
-            state, clients = _sync_weights(local_cfg, state, clients)
+            clients = _merge_positions(clients, served, n_shards, q)
+            with jax.named_scope("ditto.exchange"):
+                back = jax.lax.all_to_all(res.hit.reshape(G, n_shards, q),
+                                          AXIS, 1, 1, tiled=True)
+            with jax.named_scope("ditto.route"):
+                hits = jax.vmap(lambda hb, s: _back_merge(hb, s, lanes))(
+                    back, ss)
+            with jax.named_scope("ditto.sync"):
+                state, clients = _sync_weights(local_cfg, state, clients)
             return (state, clients, stats, precv_next), hits
 
         (state, clients, stats, _), hits = jax.lax.scan(

@@ -317,33 +317,35 @@ def resize_lanes(mesh: Mesh, local_cfg: CacheConfig, dm,
     from the current global weights with an empty FC cache.
     """
     n_shards = mesh.shape[AXIS]
-    old_total = dm.clients.fc_slot.shape[0]
-    old_lanes = old_total // n_shards
-    new_total = n_shards * new_lanes_per_shard
+    # Each shard holds lanes_per_shard lanes for every source shard
+    # (repro.dm.sharded_cache._positions): blocks of (shard, source).
+    blocks = n_shards * n_shards
+    old_lanes = dm.clients.fc_slot.shape[0] // blocks
+    new_total = blocks * new_lanes_per_shard
     if new_lanes_per_shard == old_lanes:
         return dm, ResizeReport(0, 0, 0, 0)
     before = _snapshot(dm, n_shards, local_cfg.value_words)
 
     local_slots = local_cfg.n_slots
     cl = jax.tree.map(np.asarray, dm.clients)
-    per_shard = jax.tree.map(
-        lambda x: x.reshape((n_shards, old_lanes) + x.shape[1:]), cl)
+    per_block = jax.tree.map(
+        lambda x: x.reshape((blocks, old_lanes) + x.shape[1:]), cl)
 
     freq = np.asarray(dm.state.freq).copy()
     weights = np.asarray(dm.state.weights).copy()     # [n_shards, E]
     keep = min(old_lanes, new_lanes_per_shard)
 
     if new_lanes_per_shard < old_lanes:
-        # --- decommission flush (lanes [keep:]) -------------------------
+        # --- decommission flush (lanes [keep:] of every block) ----------
         # Penalty buffers are [E] classic / [T, E] per-tenant; the fold
         # below is shape-generic (each expert row normalizes on axis -1).
-        pen_total = np.zeros(per_shard.penalty_acc.shape[2:], np.float32)
-        for s in range(n_shards):
-            fs = per_shard.fc_slot[s, keep:].reshape(-1)
-            fd = per_shard.fc_delta[s, keep:].reshape(-1)
+        pen_total = np.zeros(per_block.penalty_acc.shape[2:], np.float32)
+        for b in range(blocks):
+            fs = per_block.fc_slot[b, keep:].reshape(-1)
+            fd = per_block.fc_delta[b, keep:].reshape(-1)
             ok = (fs >= 0) & (fs < local_slots)
-            np.add.at(freq, s * local_slots + fs[ok], fd[ok])
-            pen_total += per_shard.penalty_acc[s, keep:].sum(axis=0)
+            np.add.at(freq, b // n_shards * local_slots + fs[ok], fd[ok])
+            pen_total += per_block.penalty_acc[b, keep:].sum(axis=0)
         lam = np.float32(local_cfg.learning_rate)
         w = weights[0] * np.exp(-lam * pen_total)
         w = np.maximum(
@@ -351,7 +353,7 @@ def resize_lanes(mesh: Mesh, local_cfg: CacheConfig, dm,
         weights = np.broadcast_to(w, weights.shape).copy()
 
     fresh = jax.tree.map(
-        lambda x: x.reshape((n_shards, new_lanes_per_shard) + x.shape[1:]),
+        lambda x: x.reshape((blocks, new_lanes_per_shard) + x.shape[1:]),
         jax.tree.map(np.asarray,
                      init_clients(local_cfg, new_total, seed)))
 
@@ -359,12 +361,13 @@ def resize_lanes(mesh: Mesh, local_cfg: CacheConfig, dm,
         out = np.array(new)
         out[:, :keep] = old[:, :keep]
         return out.reshape((new_total,) + out.shape[2:])
-    merged = jax.tree.map(merge, per_shard, fresh)
-    # New lanes adopt the (post-flush) global weights ([E] or [T, E]).
-    wtail = per_shard.local_weights.shape[2:]
+    merged = jax.tree.map(merge, per_block, fresh)
+    # New lanes adopt their shard's (post-flush) global weights ([E] or
+    # [T, E]).
+    wtail = per_block.local_weights.shape[2:]
     lw = merged.local_weights.reshape(
-        (n_shards, new_lanes_per_shard) + wtail)
-    lw[:, keep:] = weights[:, None]
+        (blocks, new_lanes_per_shard) + wtail)
+    lw[:, keep:] = np.repeat(weights, n_shards, axis=0)[:, None]
     merged = merged._replace(
         local_weights=lw.reshape((new_total,) + wtail))
 
@@ -389,44 +392,75 @@ def _put_like(arr, host):
     return jax.device_put(jnp.asarray(host), arr.sharding)
 
 
+def _donate() -> bool:
+    """Donate the pool through the on-device edits on an accelerator (a
+    no-op warning on the CPU), as ``execute`` does."""
+    return jax.default_backend() != "cpu"
+
+
+@functools.lru_cache(maxsize=32)
+def _wipe_fn(mesh: Mesh, donate: bool):
+    """Jitted in-place wipe of one shard's slot arrays and occupancy rows,
+    run where the pool lives: no column leaves its chip."""
+    n_shards = mesh.shape[AXIS]
+
+    def wipe(state, k):
+        def own(x, n_rows):
+            rows = jnp.arange(x.shape[0]) // (x.shape[0] // n_rows) == k
+            return rows.reshape((-1,) + (1,) * (x.ndim - 1))
+
+        def clear(x, n_rows, fill=0):
+            return jnp.where(own(x, n_rows), jnp.asarray(fill, x.dtype), x)
+        out = {name: clear(getattr(state, name), n_shards)
+               for name in ("key", "key_hash", "ptr", "insert_ts",
+                            "last_ts", "freq", "tenant", "ext", "values",
+                            "n_cached", "bytes_cached", "tenant_bytes",
+                            "hist_ctr", "gds_L")}
+        out["size"] = clear(state.size, n_shards, SIZE_EMPTY)
+        # Global L0 flush (DESIGN.md §15): the wipe — and the re-routing
+        # that follows — happens outside access_group's version bumps,
+        # and after failover the same key may be served by a different
+        # shard's lanes, so EVERY shard's epoch advances to drop all
+        # near-cache copies.  bucket_ver stays as-is (monotone): pre-wipe
+        # tokens can then never revalidate against the rebuilt table.
+        out["l0_epoch"] = state.l0_epoch + 1
+        return state._replace(**out)
+    return jax.jit(wipe, donate_argnums=(0,) if donate else (),
+                   out_shardings=NamedSharding(mesh, P(AXIS)))
+
+
 def fail_wipe_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int):
     """Ground-truth shard loss: shard k's DRAM is gone.  Zeroes its slot
-    arrays and per-shard occupancy counters in place (same host-side
-    surgery pattern as `resize_lanes`).  Control-plane scalars — the
-    logical clock and the expert weights — survive: the replacement node
-    re-syncs them on join, and keeping the clock in lockstep is what
-    makes post-recovery decisions deterministic across reruns."""
+    arrays and per-shard occupancy counters in place, on the chips.
+    Control-plane scalars — the logical clock and the expert weights —
+    survive: the replacement node re-syncs them on join, and keeping the
+    clock in lockstep is what makes post-recovery decisions
+    deterministic across reruns."""
     n_shards = mesh.shape[AXIS]
     assert 0 <= k < n_shards
-    ls = local_cfg.n_slots
-    sl = slice(k * ls, (k + 1) * ls)
-    st = dm.state
-    out = {}
-    for name in ("key", "key_hash", "ptr", "insert_ts", "last_ts",
-                 "freq", "tenant"):
-        h = np.array(getattr(st, name))
-        h[sl] = 0
-        out[name] = _put_like(getattr(st, name), h)
-    sz = np.array(st.size)
-    sz[sl] = SIZE_EMPTY
-    out["size"] = _put_like(st.size, sz)
-    for name in ("ext", "values"):
-        h = np.array(getattr(st, name))
-        h[sl] = 0
-        out[name] = _put_like(getattr(st, name), h)
-    for name in ("n_cached", "bytes_cached", "tenant_bytes", "hist_ctr",
-                 "gds_L"):
-        h = np.array(getattr(st, name))
-        h[k] = 0
-        out[name] = _put_like(getattr(st, name), h)
-    # Global L0 flush (DESIGN.md §15): the wipe — and the re-routing that
-    # follows — happens outside access_group's version bumps, and after
-    # failover the same key may be served by a different shard's lanes,
-    # so EVERY shard's epoch advances to drop all near-cache copies.
-    # bucket_ver stays as-is (monotone): pre-wipe tokens can then never
-    # revalidate against the rebuilt table.
-    out["l0_epoch"] = _put_like(st.l0_epoch, np.array(st.l0_epoch) + 1)
-    return dm._replace(state=st._replace(**out))
+    state = _wipe_fn(mesh, _donate())(dm.state, jnp.int32(k))
+    return dm._replace(state=state)
+
+
+@functools.lru_cache(maxsize=32)
+def _move_records_fn(mesh: Mesh, donate: bool):
+    """Jitted copy of records between shards: ``values[dst] = values[src]``
+    for every pair with ``dst >= 0``.  Each shard sends the rows it owns
+    (one ``psum`` of the moved rows alone); the destination shard writes
+    them.  No shard's column leaves its chip."""
+    def move(values, src, dst):
+        me = jax.lax.axis_index(AXIS)
+        n = values.shape[0]
+        mine = src // n == me
+        rows = jnp.where(mine[:, None],
+                         values[jnp.where(mine, src - me * n, 0)], 0)
+        rows = jax.lax.psum(rows, AXIS)
+        at = jnp.where((dst >= 0) & (dst // n == me), dst - me * n, n)
+        return values.at[at].set(rows, mode="drop")
+    return jax.jit(jax.shard_map(move, mesh=mesh,
+                                 in_specs=(P(AXIS), P(), P()),
+                                 out_specs=P(AXIS), check_vma=False),
+                   donate_argnums=(0,) if donate else ())
 
 
 def rewarm_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int, *,
@@ -447,8 +481,10 @@ def rewarm_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int, *,
     assert 0 <= k < n_shards
     ls, lb, A = local_cfg.n_slots, local_cfg.n_buckets, local_cfg.assoc
     st = dm.state
+    # Records stay on their chips (_move_records_fn); the metadata
+    # columns are planned here.
     names = ("key", "key_hash", "size", "ptr", "insert_ts", "last_ts",
-             "freq", "ext", "values", "tenant")
+             "freq", "ext", "tenant")
     arr = {n: np.array(getattr(st, n)) for n in names}
     kh = np.asarray(hash_key(jnp.asarray(arr["key"])))
     home = np.asarray(bucket_of(jnp.asarray(kh), lb * n_shards)) // lb
@@ -468,6 +504,8 @@ def rewarm_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int, *,
     cap_k = int(np.array(st.capacity_blocks)[k])
     multi = local_cfg.n_tenants > 1
     moved = moved_bytes = freed_blocks = 0
+    srcs = np.zeros(max_objects, np.int32)
+    dsts = np.full(max_objects, -1, np.int32)
     for s_idx in cand:
         sz = int(arr["size"][s_idx])
         if bc[k] + sz > cap_k:
@@ -482,6 +520,7 @@ def rewarm_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int, *,
         dst = base + int(free[0])
         for n in names:
             arr[n][dst] = arr[n][s_idx]
+        srcs[moved], dsts[moved] = s_idx, dst
         src = int(slot_shard[s_idx])
         arr["key"][s_idx] = 0
         arr["key_hash"][s_idx] = 0
@@ -497,6 +536,8 @@ def rewarm_shard(mesh: Mesh, local_cfg: CacheConfig, dm, k: int, *,
         freed_blocks += sz
         moved_bytes += sz * 64 + 4 * local_cfg.value_words
     out = {n: _put_like(getattr(st, n), arr[n]) for n in names}
+    out["values"] = _move_records_fn(mesh, _donate())(
+        st.values, jnp.asarray(srcs), jnp.asarray(dsts))
     out["n_cached"] = _put_like(st.n_cached, nc)
     out["bytes_cached"] = _put_like(st.bytes_cached, bc)
     out["tenant_bytes"] = _put_like(st.tenant_bytes, tb)

@@ -163,7 +163,7 @@ def run_scenario(cfg: CacheConfig, keys, timeline: Sequence[Tuple[int, Event]],
     mesh, local = cluster.mesh, cluster.local
     dm = cluster.dm
     exec_fn = jax.jit(functools.partial(dm_execute, mesh, local))
-    member = cluster.membership()
+    member = cluster.routes
     bucket_loads = np.zeros(cfg.n_buckets, np.float64)
     win_counts = np.zeros(cfg.n_buckets, np.float64)
     compiled_shapes: set = set()
@@ -238,7 +238,7 @@ def run_scenario(cfg: CacheConfig, keys, timeline: Sequence[Tuple[int, Event]],
         else:
             raise ValueError(f"unknown scenario event {name!r}")
         if member_changed:
-            member = cluster.membership()
+            member = cluster.routes
         win_mig += report.migration_bytes
         win_drain += report.drain_steps
         win_events.append(name)
@@ -368,7 +368,7 @@ def run_scenario(cfg: CacheConfig, keys, timeline: Sequence[Tuple[int, Event]],
                 win_counts[:] = 0.0
                 cluster = cluster._replace(dm=dm).elect_replicas(
                     bucket_loads, replicate_hot)
-                member = cluster.membership()
+                member = cluster.routes
 
             if width_controller is not None:
                 width_controller.propose()

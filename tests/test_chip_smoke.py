@@ -15,7 +15,8 @@ import chip_smoke  # noqa: E402
 
 TINY = dict(n_keys=20_000, n_buckets=256, capacity=1024, lanes=16,
             load_chunks=2, run_ops=4096, cap_slots=1024, cap_lanes=8,
-            cap_ops=1024, four_rounds=8, leg_rounds=8)
+            cap_ops=1024, four_rounds=8, leg_rounds=8, shard_lanes=4,
+            value_words=4)
 
 
 def _env(**kw):

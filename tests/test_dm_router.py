@@ -20,7 +20,7 @@ from repro.core import CacheConfig
 from repro.core.cache import apply_penalties
 from repro.core.types import init_clients
 from repro.dm import dm_access, dm_make
-from repro.dm.sharded_cache import _pad_clients
+from repro.dm.sharded_cache import _merge_positions, _positions
 from repro.workloads import zipfian
 
 pytestmark = pytest.mark.fast
@@ -29,20 +29,27 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_pad_clients_decorrelates_rng():
-    """Padded lanes must not replicate their source lane's rng stream —
-    identical streams draw identical sample offsets / expert choices,
-    correlating evictions across supposedly independent lanes."""
+    """Every request position a shard executes runs on a lane of its own
+    (DESIGN.md §14): no two positions share an rng stream — identical
+    streams draw identical sample offsets / expert choices, correlating
+    evictions across supposedly independent lanes — and no position
+    runs on a copy of another's state."""
     cfg = CacheConfig(n_buckets=64, assoc=8, capacity=128)
-    clients = init_clients(cfg, 2, seed=0)
-    padded = _pad_clients(clients, 8)
-    rng = np.asarray(padded.rng)
-    # original lanes keep their stored keys
-    np.testing.assert_array_equal(rng[:2], np.asarray(clients.rng))
-    # every presented lane draws a distinct stream
-    assert len({tuple(r) for r in rng.tolist()}) == 8
-    # non-rng state is still replicated verbatim
-    np.testing.assert_array_equal(np.asarray(padded.fc_slot[2:4]),
-                                  np.asarray(clients.fc_slot))
+    # two shards' worth of held lanes at one node: 2 sources x 4 lanes
+    clients = init_clients(cfg, 8, seed=0)
+    full = _positions(clients, 2, 4)
+    np.testing.assert_array_equal(np.asarray(full.rng),
+                                  np.asarray(clients.rng))
+    # a narrower routing capacity runs lanes [0, q) of every source block
+    part = _positions(clients, 2, 3)
+    rng = np.asarray(part.rng)
+    np.testing.assert_array_equal(
+        rng, np.asarray(clients.rng)[[0, 1, 2, 4, 5, 6]])
+    assert len({tuple(r) for r in rng.tolist()}) == 6
+    # what the positions advanced lands on exactly those lanes
+    moved = part._replace(fc_delta=part.fc_delta + 1)
+    back = np.asarray(_merge_positions(clients, moved, 2, 3).fc_delta)
+    np.testing.assert_array_equal(back[:, 0], [1, 1, 1, 0, 1, 1, 1, 0])
 
 
 def test_apply_penalties_clamp_then_normalize():

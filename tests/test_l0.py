@@ -237,8 +237,9 @@ def assert_l0_coherent(cluster):
     l0_tok, l0_sz = np.asarray(cl.l0_tok), np.asarray(cl.l0_sz)
     l0_val, seen = np.asarray(cl.l0_val), np.asarray(cl.l0_seen_epoch)
     checked = 0
-    for lane in range(S * lanes):
-        s = lane // lanes
+    held = l0_key.shape[0] // S     # every shard holds S * lanes lanes
+    for lane in range(l0_key.shape[0]):
+        s = lane // held
         if seen[lane] != epoch[s]:
             continue            # whole lane flushes at its next step
         for e in range(l0_key.shape[1]):

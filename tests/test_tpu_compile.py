@@ -211,7 +211,7 @@ def test_dm_execute_compiles_on_four_chips(topo):
     """The pipelined DM scan over a described 2x2 mesh at the four-chip
     smoke's pool (one smoke pool per chip): the router's all-to-all is
     in the program."""
-    from repro.dm.sharded_cache import (AXIS, DMCache, dm_execute,
+    from repro.dm.sharded_cache import (AXIS, _dm_init, dm_execute,
                                         identity_membership)
     S, lanes = 4, 64
     cfg = dataclasses.replace(SMOKE, n_buckets=S * SMOKE.n_buckets,
@@ -220,17 +220,9 @@ def test_dm_execute_compiles_on_four_chips(topo):
         cfg, n_buckets=SMOKE.n_buckets, capacity=SMOKE.capacity,
         hist_len=cfg.history_len // S)
     mesh = Mesh(np.array(topo.devices[:S]), (AXIS,))
-    per_shard = lambda x: jnp.broadcast_to(x[None], (S,) + x.shape)
 
     def build():
-        st = init_cache(cfg)
-        st = st._replace(**{f: per_shard(getattr(st, f)) for f in (
-            "n_cached", "bytes_cached", "hist_ctr", "clock", "weights",
-            "gds_L", "capacity_blocks", "tenant_bytes", "tenant_budget",
-            "l0_epoch")})
-        stats = jax.tree.map(lambda x: jnp.zeros((S,), x.dtype),
-                             init_stats())
-        return DMCache(st, init_clients(cfg, S * lanes), stats)
+        return _dm_init(cfg, local, S, lanes, 0)
 
     sharded = NamedSharding(mesh, P(AXIS))
     whole = NamedSharding(mesh, P())
@@ -245,3 +237,45 @@ def test_dm_execute_compiles_on_four_chips(topo):
         lambda d, k, w, m: dm_execute(mesh, local, d, k, w, member=m)).lower(
             dm, keys, writes, member).compile()
     assert "all-to-all" in compiled.as_text()
+
+
+def test_four_chip_cluster_holds_one_shard_a_chip(topo):
+    """The four-chip benchmark's cluster (four ditto-1chip nodes, 1 KB
+    records, 256 lanes a shard) compiled for a described 2x2 mesh: the
+    construction makes each shard on its own chip and no chip more than
+    one shard's state; the runner, its pool donated, holds one copy of a
+    shard and little else."""
+    from repro.dm.cluster import runner
+    from repro.dm.sharded_cache import (AXIS, _dm_init, _local_config,
+                                        identity_membership)
+    S, lanes = 4, 256
+    cfg = dataclasses.replace(SMOKE, n_buckets=S * SMOKE.n_buckets,
+                              capacity=S * SMOKE.capacity, value_words=256)
+    local = _local_config(cfg, S)
+    mesh = Mesh(np.array(topo.devices[:S]), (AXIS,))
+    sharded = NamedSharding(mesh, P(AXIS))
+    build = jax.jit(lambda: _dm_init(cfg, local, S, lanes, 0),
+                    out_shardings=sharded)
+    made = build.lower().compile().memory_analysis()
+    total = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(jax.eval_shape(build)))
+    assert made.output_size_in_bytes <= total // S + 2**20
+    assert made.temp_size_in_bytes <= 2**20
+    assert made.output_size_in_bytes < 9.2e9
+
+    whole = NamedSharding(mesh, P())
+    spec = lambda tree, sh: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), tree)
+    dm = spec(jax.eval_shape(build), sharded)
+    member = spec(jax.eval_shape(
+        lambda: identity_membership(S, cfg.n_buckets)), whole)
+    req = [jax.ShapeDtypeStruct((8, S * lanes), dt,
+                                sharding=NamedSharding(mesh, P(None, AXIS)))
+           for dt in (jnp.uint32, jnp.bool_)]
+    fn = runner(mesh, local, 4, True)
+    mem = fn.lower(dm, *req, None, None, member=member).compile(
+        ).memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes >= made.output_size_in_bytes - 2**20
+    assert held < 9.5e9, held
